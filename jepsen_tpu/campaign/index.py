@@ -3,7 +3,7 @@
 One file per campaign (``<store>/campaigns/<name>.jsonl``), one JSON
 record per completed run, fsync'd on append — the same durability and
 torn-line story as `parallel.batch.check_batch_checkpointed`'s
-checkpoints and the original `scripts/tpu_campaign.py` stage ledger: a
+checkpoints: a
 crash mid-append leaves at most one torn trailing line, which a reload
 drops (and truncates) before resuming.
 

@@ -23,7 +23,11 @@ Isolation + resilience per run:
 - ``executor="subprocess"`` re-invokes ``python -m
   jepsen_tpu.campaign.runner`` per run — a crashing checker (or a
   wedged backend) cannot take the campaign down, and the hard
-  ``run_deadline_s`` is enforced with a real kill;
+  ``run_deadline_s`` is enforced with a real kill.  A child that
+  initializes the TPU backend claims every chip of the host, so this
+  executor keeps ONE device slot (device children run one at a time)
+  and holds host-only children to the CPU backend
+  (`utils.backend.child_env`);
 - crashed runs retry per a seeded `resilience.RetryPolicy` (every
   exception is retryable at this level — the run may have died to an
   environment flake), and whatever survives the retries is recorded as
@@ -109,7 +113,9 @@ class Scheduler:
         if executor not in ("thread", "subprocess"):
             raise ValueError(f"unknown executor {executor!r}")
         self.n_workers = max(1, int(n_workers))
-        self.slots = DeviceSlots(device_slots)
+        # subprocess: one device-using child per host at a time
+        self.slots = DeviceSlots(
+            1 if executor == "subprocess" else device_slots)
         self.executor = executor
         #: optional telemetry.Heartbeat: per-worker in-flight state
         #: published to the campaign ledger dir as runs start/finish —
@@ -141,7 +147,7 @@ class Scheduler:
             q.put((i, rs))
         results: List[Optional[Dict[str, Any]]] = [None] * len(specs)
         lock = threading.Lock()
-        # queue-wait accounting (ISSUE 16 phase taxonomy): a parked run
+        # queue-wait accounting (ISSUE 16 phase buckets): a parked run
         # stamps its park time; the dequeue that finally proceeds books
         # the gap.  Entries are only ever touched by the thread holding
         # that queue item, so plain dicts suffice.
@@ -263,7 +269,7 @@ class Scheduler:
             attempt += 1
             try:
                 if self.executor == "subprocess":
-                    rec = self._run_subprocess(rs, slot)
+                    rec = self._run_subprocess(rs)
                 else:
                     # pin this thread's device slice to the acquired
                     # slot: the run's device checks then build their
@@ -300,18 +306,16 @@ class Scheduler:
 
     # -- subprocess isolation ------------------------------------------------
 
-    def _run_subprocess(self, rs: RunSpec, slot: Optional[int]
-                        ) -> Dict[str, Any]:
+    def _run_subprocess(self, rs: RunSpec) -> Dict[str, Any]:
         """One run in its own interpreter: `python -m
         jepsen_tpu.campaign.runner` reads the RunSpec JSON on argv,
         prints the index record as its last stdout line.  A deadline
         overrun is a hard kill -> attributable unknown."""
         base = rs.opts.get("_base") or "store"
         payload = json.dumps({"runspec": rs.to_dict(), "base": base})
-        env = dict(os.environ)
-        if slot is not None:
-            env["JEPSEN_CAMPAIGN_DEVICE_SLOT"] = str(slot)
-            env["JEPSEN_CAMPAIGN_DEVICE_SLOTS"] = str(self.slots.n)
+        from jepsen_tpu.utils.backend import child_env
+
+        env = child_env(use_device=bool(rs.device))
         try:
             r = subprocess.run(
                 [sys.executable, "-m", "jepsen_tpu.campaign.runner"],

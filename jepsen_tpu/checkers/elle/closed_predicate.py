@@ -32,7 +32,7 @@ predicate read T:
                      writer(next(u)).  Ambiguous bindings emit nothing —
                      exactness first, no false positives.
 
-Cycles are hunted with the shared taxonomy (`txn_cycles`, device rank
+Cycles are hunted with the shared classification (`txn_cycles`, device rank
 sweep + host classification); cycles traversing a phantom edge are
 reported with the `-predicate` suffix (G2-predicate etc.), mirroring the
 reference's predicate-anomaly naming.
@@ -245,7 +245,7 @@ def check(history, consistency_models: Sequence[str] = ("serializable",),
                           orig_index=orig_index)
 
     # cycles through a phantom edge are predicate anomalies — rename,
-    # matching the reference's predicate taxonomy
+    # matching the reference's predicate classification
     orig_to_internal = {int(orig_index[i]): i for i in range(T)}
     for name in list(cyc.keys()):
         items = cyc.pop(name)

@@ -103,6 +103,10 @@ class PaddedLA:
     #                                and in-bounds over has-elems reads
     proc_seq: bool = False         # static: within each process,
     #                                invoke_pos increases with txn row
+    spmd: bool = False             # static: arrays placed over a multi-
+    #                                device mesh (GSPMD); Mosaic kernels
+    #                                cannot be auto-partitioned, so infer
+    #                                takes the lax fills there
     # IR derived-order columns (history/ir.py, docs/IR.md): computed
     # ONCE host-side at pad time and reused by every check over the same
     # history — the in-program sorts/scatters they replace are the top
@@ -129,7 +133,7 @@ jax.tree_util.register_dataclass(
                  "barrier_order", "barrier_bi"],
     meta_fields=["n_keys", "n_vals", "txn_major", "run_cap",
                  "complete_monotone", "v_cap", "o_cap", "app_val_mono",
-                 "rd_start_mono", "proc_seq"],
+                 "rd_start_mono", "proc_seq", "spmd"],
 )
 
 # Above this many mops in one txn the shifted-compare ranking (2*(cap-1)
@@ -365,6 +369,7 @@ def pad_packed(p: PackedTxns, t_pad: int = 0, m_pad: int = 0,
 @partial(jax.jit, static_argnames=("n_keys",))
 def infer(h: PaddedLA, n_keys: int) -> Dict[str, dict]:
     """Full inference: anomaly flags + dependency edges + chains + ranks."""
+    use_fill = pallas_fill.fill_enabled() and not h.spmd
     T = h.txn_type.shape[0]
     M = h.mop_txn.shape[0]
     R = h.rd_elems.shape[0]
@@ -526,7 +531,7 @@ def infer(h: PaddedLA, n_keys: int) -> Dict[str, dict]:
         src_start = jnp.where(
             src_read0 >= 0,
             h.mop_rd_start[jnp.clip(src_read0, 0, M - 1)], 0)
-    elif pallas_fill.fill_enabled():
+    elif use_fill:
         # TPU: the three slot_key-indexed expansions (slot_key itself,
         # ord_start[slot_key], rd_start[ord_read[slot_key]]) are
         # monotone/segment-constant fills — seed per-key values at the
@@ -600,7 +605,7 @@ def infer(h: PaddedLA, n_keys: int) -> Dict[str, dict]:
             jnp.where(has_elems, h.mop_rd_start, R)].max(
             jnp.where(has_elems, vals.astype(jnp.int32), -1))[:R]
 
-    if pallas_fill.fill_enabled():
+    if use_fill:
         # TPU: forward-fill the owning-read id AND the four per-read
         # table values in one Pallas pass each, replacing lax.cummax
         # plus four R-sized `table[er]` gathers (~0.45 s each at
@@ -728,7 +733,7 @@ def infer(h: PaddedLA, n_keys: int) -> Dict[str, dict]:
     # element-side content check: element at offset o of read m belongs to
     # the appends-since-last-read window iff o >= base; it must then equal
     # the append at run position q(m) - n + (o - base)
-    if pallas_fill.fill_enabled():
+    if use_fill:
         # same Pallas LOCF expansion as the read-element table above:
         # all four are per-read constants, so compose them per-mop
         # (M-sized gathers, ~4x cheaper than R-sized on chip), seed at

@@ -3,7 +3,7 @@
 `check()` here is API-compatible with `jepsen_tpu.checkers.elle.oracle.check`
 (the exact host reference) and with the capability surface of the
 reference's `elle.list-append/check` (SURVEY.md §2.3): same anomaly
-taxonomy, same consistency-model verdicts.
+classification, same consistency-model verdicts.
 
 Split of labor (mirrors the reference's SCC-on-graph / search-in-SCC split,
 relocated to TPU):

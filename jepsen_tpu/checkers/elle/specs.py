@@ -2,7 +2,7 @@
 
 Each spec names a cycle-shaped anomaly, the dependency rels whose projection
 to search, and the constraint on rw (anti-dependency) edges in the cycle
-(SURVEY.md §2.3 cycle taxonomy engine).
+(SURVEY.md §2.3 cycle classification engine).
 """
 
 from __future__ import annotations

@@ -12,7 +12,7 @@ test_queue_checkers.py).
 - :mod:`.packed` — pack send/poll/assign/offset-commit histories into
   per-key offset ladders, per-consumer observation rows, and pack-time
   derived orders (``HistoryIR.queue(kind)`` memoizes both views);
-- :mod:`.kafka` — the kafka anomaly taxonomy (lost-write, duplicate,
+- :mod:`.kafka` — the kafka anomaly classification (lost-write, duplicate,
   inconsistent-offsets, poll/send order, precommitted-read,
   stale-consumer-group) as one fused mask kernel;
 - :mod:`.fifo` — the total-queue counting model + the opt-in

@@ -1,4 +1,4 @@
-"""Kafka anomaly taxonomy as whole-history vectorized reductions.
+"""Kafka anomaly classification as whole-history vectorized reductions.
 
 Every pass `workloads.kafka.KafkaChecker` runs as a python scan over
 (send, poll) tuples becomes an array reduction over the

@@ -1,17 +1,15 @@
 """Shape-bucketed AOT executable cache (ROADMAP item 1).
 
-``scripts/aot_warm.py`` + ``scripts/cache_key_probe.py`` prototyped
-compile-cost amortization as one-off scripts; this package is the
-supported machinery.  Three layers:
+Three layers:
 
 - :mod:`.bucket` — the shape-class policy: calls over pow2-padded
   arrays key into (site, dtype-signature, padded dims) classes, so
   shrink probes, campaign cells, verifier sweep chunks, and fleet
   workers share executables instead of compiling per exact shape;
-- :mod:`.store` — the persistent entries under
-  ``<store>/compilecache/``: AOT-serialized executables keyed by a
-  content fingerprint (program HLO digest x shape class x
-  backend/platform string x jax version), self-verifying on read;
+- :mod:`.store` — the persistent entries (see Enablement):
+  AOT-serialized executables keyed by a content fingerprint (program
+  HLO digest x shape class x backend/platform string x jax version),
+  self-verifying on read;
 - this module — the guarded load-or-compile seam, :func:`call`:
   in-memory executable table hit -> dispatch the cached ``Compiled``
   directly; miss -> lower, try the disk entry
@@ -25,9 +23,10 @@ supported machinery.  Three layers:
 Enablement: on by default.  ``JT_COMPILECACHE=0|off`` disables;
 ``JT_COMPILECACHE=mem`` keeps the in-process executable table but no
 disk persistence; ``JT_COMPILECACHE=<path>`` pins the store
-directory.  Unset, the store lives at ``<store>/compilecache/`` when
-the store directory exists, else memory-only — the same "never grows
-a new filesystem footprint by itself" rule as the warehouse.
+directory.  Unset, the store lives under JAX's own persistent cache
+directory (``$JAX_COMPILATION_CACHE_DIR/aot``) when that variable is
+set, else memory-only: the process never picks a disk location of its
+own, so whoever places JAX's cache places this one too.
 
 The in-memory table is LRU-bounded (``JT_COMPILECACHE_MEM``, default
 64 executables) and :func:`clear`-able — tests clear it between
@@ -101,11 +100,12 @@ def cache_dir() -> Optional[str]:
         return None
     if env and low not in ("1", "on", "true"):
         return env  # an explicit path
-    from jepsen_tpu import store as jstore
+    return _jax_cache_subdir()
 
-    if os.path.isdir(jstore.BASE):
-        return os.path.join(jstore.BASE, "compilecache")
-    return None
+
+def _jax_cache_subdir() -> Optional[str]:
+    jax_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    return os.path.join(jax_dir, "aot") if jax_dir else None
 
 
 def set_cache_dir(path: Optional[str]) -> None:
@@ -118,12 +118,14 @@ def set_cache_dir(path: Optional[str]) -> None:
 
 def adopt_base(base: str) -> Optional[str]:
     """Point the persistent store at ``<base>/compilecache`` unless an
-    explicit JT_COMPILECACHE path (or a prior override) already pinned
-    one — the fleet worker's store-base adoption."""
+    explicit JT_COMPILECACHE setting, a prior override, or
+    ``JAX_COMPILATION_CACHE_DIR`` already placed it — the fleet
+    worker's store-base adoption."""
     env = os.environ.get("JT_COMPILECACHE", "").strip()
     if _dir_override is not _UNSET:
         return cache_dir()
-    if env and env.lower() not in ("1", "on", "true"):
+    if (env and env.lower() not in ("1", "on", "true")) \
+            or _jax_cache_subdir():
         return cache_dir()
     d = os.path.join(base, "compilecache")
     set_cache_dir(d)
@@ -214,12 +216,22 @@ def _platform() -> str:
     return f"{jax.default_backend()}|{ver}|jax-{jax.__version__}"
 
 
+def _devices_by_id(ids) -> list:
+    """The executable's own devices, in its device-assignment order: a
+    reload without them binds to every local device and then rejects
+    a single-device call (jax >= 0.9)."""
+    import jax
+
+    by_id = {dv.id: dv for dv in jax.devices()}
+    return [by_id[i] for i in ids]
+
+
 def _fingerprint(lowered: Any, site: str, args: tuple,
                  static: dict) -> str:
     """The content fingerprint: program HLO digest x shape class x
-    backend/platform string (the cache_key_probe discipline — of the
-    probe's 8 key components only platform/accelerator vary across
-    backends, so these three factors are the sufficient key)."""
+    backend/platform string (of JAX's own cache-key components only
+    platform/accelerator vary across backends, so these three factors
+    are the sufficient key)."""
     hlo = hashlib.sha256(lowered.as_text().encode()).hexdigest()
     cls = bucket.class_digest(site, args, static)
     plat = hashlib.sha256(_platform().encode()).hexdigest()[:16]
@@ -229,8 +241,13 @@ def _fingerprint(lowered: Any, site: str, args: tuple,
 
 def _mem_key(site: str, jitfn: Callable, args: tuple,
              static: dict) -> Optional[Tuple]:
+    import jax
+
     try:
+        # the tree structure carries static pytree fields (e.g.
+        # PaddedLA.spmd), which select a different program
         return (site, _fn_ident(jitfn), bucket.signature(args),
+                jax.tree_util.tree_structure(args),
                 bucket.static_signature(static))
     except Exception:  # noqa: BLE001 — exotic args must not fail a call
         return None
@@ -285,7 +302,9 @@ def _obtain(site: str, jitfn: Callable, args: tuple, static: dict
         if got is not None:
             doc, size = got
             try:
-                compiled = _se.deserialize_and_load(*doc["payload"])
+                devs = _devices_by_id(doc["meta"]["devices"])
+                compiled = _se.deserialize_and_load(
+                    *doc["payload"], execution_devices=devs)
                 _bump("bytes", size)
                 _count("compile-cache-bytes", size)
                 return compiled, "loaded", (d, fp)
@@ -305,6 +324,8 @@ def _obtain(site: str, jitfn: Callable, args: tuple, static: dict
                 "site": site,
                 "class": bucket.class_label(site, args, static),
                 "platform": _platform(),
+                "devices": [dv.id for dv in
+                            compiled.runtime_executable().local_devices()],
             }, payload)
             _bump("bytes", n)
             _count("compile-cache-bytes", n)

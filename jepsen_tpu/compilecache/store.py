@@ -1,8 +1,7 @@
-"""The persistent AOT entry store: ``<store>/compilecache/*.aotx``.
+"""The persistent AOT entry store: ``<cache dir>/*.aotx``.
 
 One file per executable, named by its content fingerprint (program
-HLO digest x shape class x backend/platform string — the key
-discipline ``scripts/cache_key_probe.py`` validated).  File format::
+HLO digest x shape class x backend/platform string).  File format::
 
     JTCC1\\n  <sha256-hex of payload>\\n  <payload>
 

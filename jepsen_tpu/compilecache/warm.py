@@ -1,10 +1,8 @@
 """Bucket-ladder pre-warm: populate the AOT store at service start.
 
-The graft of ``scripts/aot_warm.py`` into supported machinery: instead
-of a one-off script lowering the 10M TPU programs, :func:`warm_ladder`
-walks the default bucket ladder (:data:`bucket.LADDER`, overridable
-via ``--sizes``, capped/extended to ``--max-txns``'s bucket) and
-ensures every rung's checker
+:func:`warm_ladder` walks the default bucket ladder
+(:data:`bucket.LADDER`, overridable via ``--sizes``, capped/extended to
+``--max-txns``'s bucket) and ensures every rung's checker
 executables exist in the persistent store — so the first shrink probe,
 campaign cell, or fleet claim of a known shape class pays dispatch,
 not compile.
@@ -20,9 +18,9 @@ useless — pinned by tests/test_compilecache.py):
 - ``rw``: the fused `elle.rw-core-check`.
 
 Fused/infer programs are lowered at abstract ``ShapeDtypeStruct``
-shapes (aot_warm's ``_sds`` idiom — no multi-GB arrays held through
-the compile); the sharded program is lowered from concretely placed
-shards, since its executable bakes the input shardings.
+shapes (no multi-GB arrays held through the compile); the sharded
+program is lowered from concretely placed shards, since its executable
+bakes the input shardings.
 
 Every rung is individually guarded: a failed warm records the error
 and moves on (``compilecache.warm`` is a chaos seam —

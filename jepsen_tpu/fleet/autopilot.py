@@ -400,7 +400,7 @@ class Autopilot:
         self.journal = AutopilotJournal(
             autopilot_path(self.name, self.base))
         #: managed worker subprocesses:
-        #: name -> {proc, version, spawned, draining}
+        #: name -> {proc, device, version, spawned, draining}
         self.workers: Dict[str, Dict[str, Any]] = {}
         self._wseq = 0
         self._upgrading: Optional[Tuple[str, str]] = None
@@ -920,10 +920,17 @@ class Autopilot:
 
         if not self.coordinator_url:
             return None
+        from jepsen_tpu.utils.backend import child_env
+
         self._wseq += 1
         name = f"ap-{os.getpid()}-{self._wseq}"
-        env = dict(os.environ,
-                   JEPSEN_WORKER_VERSION=self.worker_version)
+        # a worker that initializes the TPU backend claims every chip
+        # of the host: only one live worker gets the device, the rest
+        # check on the CPU backend
+        use_device = not any(w["device"] and w["proc"].poll() is None
+                             for w in self.workers.values())
+        env = child_env(use_device)
+        env["JEPSEN_WORKER_VERSION"] = self.worker_version
         cmd = [sys.executable, "-m", "jepsen_tpu",
                "--store-dir", self.base, "fleet", "work",
                "--coordinator", self.coordinator_url,
@@ -933,6 +940,7 @@ class Autopilot:
                                 stdout=subprocess.DEVNULL,
                                 stderr=subprocess.DEVNULL)
         self.workers[name] = {"proc": proc,
+                              "device": use_device,
                               "version": self.worker_version,
                               "spawned": round(time.time(), 3),
                               "draining": False}

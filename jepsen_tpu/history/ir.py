@@ -48,7 +48,7 @@ __all__ = ["IR_VERSION", "HistoryIR"]
 def _booked(build):
     """Run one cache-miss section build, booking its wall as
     ``host_pack_s`` phase self-time on the enclosing telemetry span
-    (ISSUE 16 phase taxonomy) — memoized hits pay nothing."""
+    (ISSUE 16 phase classification) — memoized hits pay nothing."""
     from jepsen_tpu.telemetry import spans as _spans
 
     t0 = time.perf_counter()

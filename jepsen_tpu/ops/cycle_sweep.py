@@ -190,11 +190,8 @@ def _sweep_window(n_nodes: int, k_total: int, k_local: int, max_rounds: int,
         if axis_name is not None:
             # the label plane is varying over the mesh axis (its window
             # depends on axis_index), so the whole carry must be too
-            # (no-op on jax versions without the varying-type system)
-            from jepsen_tpu.utils.backend import pcast_varying
-
-            changed0 = pcast_varying(changed0, axis_name)
-            rounds0 = pcast_varying(rounds0, axis_name)
+            changed0 = jax.lax.pcast(changed0, axis_name, to="varying")
+            rounds0 = jax.lax.pcast(rounds0, axis_name, to="varying")
         labels, changed, rounds = jax.lax.while_loop(
             cond, body, (chain_pass(labels0), changed0, rounds0))
         converged = ~(changed & (rounds >= max_rounds))
@@ -288,15 +285,12 @@ def _sweep_sharded(n_nodes: int, max_k: int, max_rounds: int, mesh, axis,
     merges with one all_gather.  Same result contract as `_sweep`."""
     from jax.sharding import PartitionSpec as P
 
-    from jepsen_tpu.utils.backend import get_shard_map
-
     n_shards = mesh.shape[axis]
     assert max_k % n_shards == 0, (max_k, n_shards)
     k_local = max_k // n_shards
-    shard_map = get_shard_map()
     rep = P()
 
-    @partial(shard_map, mesh=mesh, in_specs=(rep,) * 7,
+    @partial(jax.shard_map, mesh=mesh, in_specs=(rep,) * 7,
              out_specs=(rep, rep, rep, rep))
     def run(rank_, s_, d_, m_, cn_, cs_, cm_):
         off = jax.lax.axis_index(axis) * k_local

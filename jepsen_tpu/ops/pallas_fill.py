@@ -140,7 +140,10 @@ def _locf_pallas_padded(v2d: jnp.ndarray, block: int) -> jnp.ndarray:
                                memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec((block, lanes), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
+        # vma: inside shard_map the output varies over the mesh axes
+        # its input varies over (jax >= 0.9 checks this)
+        out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.int32,
+                                       vma=jax.typeof(v2d).vma),
         scratch_shapes=[pltpu.VMEM((8, lanes), jnp.int32)],
     )(v2d)
 
@@ -182,8 +185,8 @@ def locf_blocked_reference(x: jnp.ndarray,
     return jnp.concatenate(outs).reshape(-1)[:n]
 
 
-#: default-on for the TPU backend once scripts/tpu_fill_bench.py has
-#: validated the compiled kernel bitwise against the lax scan on chip
+#: default-on for the TPU backend (the kernel is held bitwise to the lax
+#: scan by tests/test_pallas_fill.py through the grid emulator)
 _TPU_VALIDATED = True
 
 

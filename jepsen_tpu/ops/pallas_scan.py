@@ -118,7 +118,11 @@ def _seg_or_pallas_padded(values: jnp.ndarray, starts_i8: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((block, k), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n, k), jnp.int8),
+        # vma: inside shard_map the output varies over the mesh axes
+        # its inputs vary over (jax >= 0.9 checks this)
+        out_shape=jax.ShapeDtypeStruct(
+            (n, k), jnp.int8,
+            vma=jax.typeof(values).vma | jax.typeof(starts_i8).vma),
         scratch_shapes=[pltpu.VMEM((8, k), jnp.int32)],
     )(values, starts_i8)
 

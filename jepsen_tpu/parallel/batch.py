@@ -26,9 +26,6 @@ from jepsen_tpu import resilience, telemetry
 from jepsen_tpu.checkers.elle.device_core import core_check
 from jepsen_tpu.checkers.elle.device_infer import PaddedLA, pad_packed
 from jepsen_tpu.history.soa import PackedTxns
-from jepsen_tpu.utils.backend import get_shard_map
-
-shard_map = get_shard_map()
 
 
 def make_mesh(n_devices: int = 0, axis: str = "dp") -> Mesh:
@@ -139,7 +136,7 @@ def _batched_sharded(batch: PaddedLA, *, n_keys: int, mesh: Mesh,
     shard_map program the old per-call closure built."""
     spec = P(axis)
 
-    @partial(shard_map, mesh=mesh, in_specs=(spec,),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(spec,),
              out_specs=(spec, spec))
     def rows(b):
         return jax.vmap(lambda h: core_check(h, n_keys))(b)

@@ -45,9 +45,6 @@ from jepsen_tpu.parallel.batch import (
     summarize_batch_bits,
 )
 from jepsen_tpu.parallel.op_shard import projection_sweep_bits
-from jepsen_tpu.utils.backend import get_shard_map
-
-shard_map = get_shard_map()
 
 
 def make_hybrid_mesh(n_dcn: int, n_k: int, devices=None) -> Mesh:
@@ -66,7 +63,7 @@ def _hybrid_core(batch, n_keys: int, mesh: Mesh, max_k: int = 128,
 
     bspec = P("dcn")
 
-    @partial(shard_map, mesh=mesh, in_specs=(bspec,),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(bspec,),
              out_specs=(bspec, bspec))
     def rows(b):
         def one(h):

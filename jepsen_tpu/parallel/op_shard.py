@@ -38,6 +38,7 @@ an entry point — docs/IR.md).
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Optional
 
@@ -54,9 +55,6 @@ from jepsen_tpu.checkers.elle.device_core import (
 from jepsen_tpu.checkers.elle.device_infer import PaddedLA, infer, pad_packed
 from jepsen_tpu.history.soa import PackedTxns
 from jepsen_tpu.ops.cycle_sweep import _sweep_window
-from jepsen_tpu.utils.backend import get_shard_map
-
-shard_map = get_shard_map()
 
 
 def projection_sweep_bits(out, max_k: int, sweep):
@@ -126,7 +124,7 @@ def _core_check_sharded(h: PaddedLA, n_keys: int, mesh: Mesh, axis: str,
     T = h.txn_type.shape[0]
     rep = P()
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(rep,) * 12, out_specs=(rep, rep, rep, rep))
     def sharded_sweep(rank_, e_src_, e_dst_, m_, cn_, cs_, cm_,
                       ib_, bid_, nb_, bsrc_, bdst_):
@@ -166,7 +164,8 @@ def shard_padded(h: PaddedLA, mesh: Mesh, axis: str = "dp"
         any_sharded = any_sharded or divisible
         return jax.device_put(x, sharded if divisible else replicated)
 
-    placed = jax.tree_util.tree_map(put, h)
+    placed = dataclasses.replace(jax.tree_util.tree_map(put, h),
+                                 spmd=n > 1)
     return placed, any_sharded
 
 

@@ -3,10 +3,9 @@
 The sharded-by-default decision point (ISSUE 12): every single-history
 device check resolves its mesh here —
 
-- the visible device set is this process's **slot slice** when a
-  campaign/fleet scheduler assigned one (`set_active_slot`, or the
-  ``JEPSEN_CAMPAIGN_DEVICE_SLOT``/``..._SLOTS`` env pair the subprocess
-  runner exports), so one host drives N sub-meshes concurrently;
+- the visible device set is this thread's **slot slice** when a
+  campaign/fleet scheduler assigned one (`set_active_slot`), so one
+  host process drives N sub-meshes concurrently;
 - ``JEPSEN_SHARDS`` forces a shard count (``1`` disables sharding);
 - otherwise a history is checked sharded over ALL visible devices as
   a 1-D ``Mesh(("batch",))`` once it is big enough to amortize the
@@ -38,8 +37,7 @@ _mesh_cache: dict = {}
 def set_active_slot(slot: Optional[int], n_slots: int = 1) -> None:
     """Pin this THREAD's device slice to campaign slot `slot` of
     `n_slots` (None clears).  The campaign scheduler calls this around
-    each device run; the subprocess runner exports the env pair
-    instead."""
+    each device run of its thread executor."""
     _local.slot = None if slot is None else (int(slot), max(1, int(n_slots)))
 
 
@@ -64,19 +62,8 @@ def _forced_shards() -> Optional[int]:
 
 
 def active_slot() -> Optional[Tuple[int, int]]:
-    """(slot, n_slots) for this thread, the env pair, or None."""
-    sl = getattr(_local, "slot", None)
-    if sl is not None:
-        return sl
-    env = os.environ.get("JEPSEN_CAMPAIGN_DEVICE_SLOT")
-    if env is None:
-        return None
-    try:
-        return (int(env),
-                max(1, int(os.environ.get(
-                    "JEPSEN_CAMPAIGN_DEVICE_SLOTS", 1))))
-    except ValueError:
-        return None
+    """(slot, n_slots) for this thread, or None."""
+    return getattr(_local, "slot", None)
 
 
 def slot_devices(slot: int, n_slots: int, devices=None) -> List:

@@ -2,7 +2,7 @@
 
 PR 14's ``obs gate`` is a tripwire: rc 1 when a span's p95 regressed.
 This module turns the trip into a diagnosis — WHERE inside the span the
-extra time went (the phase-bucket taxonomy ``spans.PHASE_BUCKETS``) and
+extra time went (the phase-bucket classification ``spans.PHASE_BUCKETS``) and
 WHAT co-moved with it (compile-cache misses, retries, requeues, sweep
 dispatches) — so a perf PR cites machine-generated before/after
 attribution instead of a hand-run bench.

@@ -454,7 +454,7 @@ def current() -> Optional[Span]:
     return _active.current()
 
 
-#: the phase self-time taxonomy (ISSUE 16): where a span's wall time
+#: the phase self-time classification (ISSUE 16): where a span's wall time
 #: actually went.  compile_s/execute_s predate this list (stamped by
 #: `resilience.guard._stamp_device_time`); the rest are accumulated by
 #: their owning subsystems via :func:`add_phase`.  Bucket attrs are

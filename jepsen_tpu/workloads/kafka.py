@@ -20,7 +20,7 @@ Op shapes mirror the reference:
 - ``{"f": "crash", ...}`` — client crashes (:info), leaves the group,
   forcing a rebalance.
 
-The checker covers the reference's anomaly taxonomy:
+The checker covers the reference's anomaly classification:
 
 - **lost-write**: a committed send whose offset is below some polled
   offset for that key, yet never polled by anyone;
@@ -490,7 +490,7 @@ def _observations(history):
 
 
 class KafkaChecker(checker_api.Checker):
-    """The reference kafka checker's anomaly taxonomy (module docstring)."""
+    """The reference kafka checker's anomaly classes (module docstring)."""
 
     def check(self, test, history, opts=None):
         sends, polls, reassigns, send_invoked = _observations(history)

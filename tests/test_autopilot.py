@@ -391,6 +391,36 @@ def test_host_info_series_pinned_to_alive_versioned_workers():
         'jepsen_fleet_host_info{host="w2",version="v2"} 1']
 
 
+def test_spawned_workers_one_device_worker_per_host(tmp_path,
+                                                   monkeypatch):
+    """A `fleet work` process that initializes the TPU backend claims
+    every chip of the host: only one live spawned worker may use the
+    device, the rest are held to the CPU backend — and when the device
+    worker dies, the next spawn takes the device over."""
+    monkeypatch.delenv("JT_FORCE_CPU", raising=False)
+    envs = []
+
+    class FakeProc:
+        def __init__(self, cmd, env, **kw):
+            envs.append(env)
+            self.rc = None
+
+        def poll(self):
+            return self.rc
+
+    monkeypatch.setattr(subprocess, "Popen", FakeProc)
+    ap = Autopilot(SPEC, str(tmp_path / "store"),
+                   coordinator_url="http://127.0.0.1:1")
+    names = [ap._spawn_worker() for _ in range(3)]
+    assert [ap.workers[n]["device"] for n in names] == [True, False, False]
+    assert "JT_FORCE_CPU" not in envs[0]
+    assert all(e["JT_FORCE_CPU"] == "1" and e["JAX_PLATFORMS"] == "cpu"
+               for e in envs[1:])
+    ap.workers[names[0]]["proc"].rc = -9
+    assert ap.workers[ap._spawn_worker()]["device"] is True
+    assert "JT_FORCE_CPU" not in envs[3]
+
+
 def test_soak_autopilot_fast():
     """The unattended acceptance: generations streamed, a seeded
     regression gate-caught -> quarantined -> auto-shrunk, the

@@ -122,6 +122,52 @@ def test_telemetric_serialization_honors_env_optin(monkeypatch):
     assert max(worst) == 1
 
 
+def test_subprocess_executor_one_device_child_per_host(monkeypatch):
+    """A child that initializes the TPU backend claims every chip of
+    the host: the subprocess executor runs device children one at a
+    time (whatever device_slots asks), each with the parent's backend
+    env, and holds host-only children to the CPU backend."""
+    import subprocess as _sp
+    import threading
+    import time as _t
+
+    from jepsen_tpu.campaign import scheduler as sched_mod
+
+    monkeypatch.delenv("JT_FORCE_CPU", raising=False)
+    active, worst, envs, lk = [], [], {}, threading.Lock()
+
+    def fake_run(cmd, input, env, **kw):
+        rs = json.loads(input)["runspec"]
+        with lk:
+            envs[rs["run_id"]] = env
+            if rs["device"]:
+                active.append(1)
+                worst.append(len(active))
+        _t.sleep(0.03)
+        with lk:
+            if rs["device"]:
+                active.pop()
+        rec = {"run": rs["run_id"], "valid?": True}
+        return _sp.CompletedProcess(cmd, 0, json.dumps(rec) + "\n", "")
+
+    monkeypatch.setattr(sched_mod.subprocess, "run", fake_run)
+    specs = [RunSpec(run_id=f"d{i}", campaign="c", workload="w", seed=i,
+                     workload_label="w", device=True) for i in range(4)]
+    specs += [RunSpec(run_id="h0", campaign="c", workload="w", seed=9,
+                      workload_label="w")]
+    sched = Scheduler(4, device_slots=4, executor="subprocess")
+    recs = sched.run(specs, None)
+    assert len(recs) == 5
+    assert sched.slots.n == 1
+    assert max(worst) == 1
+    for i in range(4):
+        assert "JT_FORCE_CPU" not in envs[f"d{i}"]
+        assert not any(k.startswith("JEPSEN_CAMPAIGN_DEVICE_SLOT")
+                       for k in envs[f"d{i}"])
+    assert envs["h0"]["JT_FORCE_CPU"] == "1"
+    assert envs["h0"]["JAX_PLATFORMS"] == "cpu"
+
+
 def test_op_shard_guard_not_nested():
     """The sharded sweep's fault site must fire ONCE per dispatch
     (site parallel.op-shard), not once per nesting level — nested

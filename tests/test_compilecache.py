@@ -58,6 +58,53 @@ def _arange(n):
     return jnp.arange(n, dtype=jnp.float32)
 
 
+# -- placement ---------------------------------------------------------------
+
+def test_compile_cache_placed_by_env(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set (conftest sets it before jax
+    is imported), JAX's cache directory is that directory, no code
+    moves it, and the AOT store lives under it — the fleet's store-base
+    adoption included."""
+    import jax
+
+    from jepsen_tpu.utils import backend
+
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not d:
+        pytest.skip("JT_NO_TEST_CACHE: the suite runs without the cache")
+    assert jax.config.jax_compilation_cache_dir == d
+    assert backend.enable_compile_cache() == d
+    assert jax.config.jax_compilation_cache_dir == d
+    monkeypatch.delenv("JT_COMPILECACHE", raising=False)
+    compilecache._dir_override = compilecache._UNSET
+    aot = os.path.join(d, "aot")
+    assert compilecache.cache_dir() == aot
+    assert compilecache.adopt_base("/elsewhere") == aot
+    assert compilecache.cache_dir() == aot
+
+
+def test_compile_cache_default_without_env(monkeypatch):
+    """Without JAX_COMPILATION_CACHE_DIR, JAX's cache is the fixed
+    <repo>/.jax_cache and the AOT store stays memory-only."""
+    import jax
+
+    from jepsen_tpu.utils import backend
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("JT_COMPILECACHE", raising=False)
+    compilecache._dir_override = compilecache._UNSET
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        assert backend.enable_compile_cache() == \
+            os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == \
+            os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    assert compilecache.cache_dir() is None
+
+
 # -- bucket policy -----------------------------------------------------------
 
 

@@ -352,8 +352,8 @@ def test_layout_facts_reject_non_txn_major():
 
 @pytest.mark.parametrize("seed", range(4))
 def test_staged_core_check_matches_fused(seed):
-    """core_check_staged (two XLA programs, the 10M remote-compile
-    workaround) is bitwise-equal to the fused core_check — valid and
+    """core_check_staged (two XLA programs, the split used at 2^24-txn
+    shapes) is bitwise-equal to the fused core_check — valid and
     injected-invalid histories both."""
     from jepsen_tpu.checkers.elle.device_core import (core_check,
                                                       core_check_staged)

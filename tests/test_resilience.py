@@ -39,7 +39,7 @@ _XlaRuntimeError.__name__ = "XlaRuntimeError"
 
 # ---------------------------------------------------------------- classifier
 
-def test_transient_classifier_xla_taxonomy():
+def test_transient_classifier_xla_errors():
     assert is_transient(_XlaRuntimeError("RESOURCE_EXHAUSTED: oom"))
     assert is_transient(_XlaRuntimeError("UNAVAILABLE: device lost"))
     assert is_transient(_XlaRuntimeError("INTERNAL: failed to compile"))

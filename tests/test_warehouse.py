@@ -814,6 +814,27 @@ def test_bench_self_ingest_never_creates_a_store(tmp_path, monkeypatch):
     assert os.path.exists(tmp_path / "w.sqlite")
 
 
+def test_bench_refuses_cpu_backend(tmp_path, monkeypatch, capsys):
+    """bench.py measures on the chip only: run in-process under the
+    test's CPU backend, its entry exits non-zero and prints an error
+    line, never a CPU number."""
+    import importlib.util
+    import json as _json
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "bench_mod", os.path.join(repo, "bench.py"))
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setenv("BENCH_SIZES", "64")
+    monkeypatch.delenv("BENCH_EMIT_CAMPAIGN_SPEC", raising=False)
+    assert bench.main() != 0
+    line = _json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["value"] == 0 and "n_txns" not in line
+    assert "no TPU" in line["error"]
+
+
 def test_obs_sql_cte_write_refused_at_engine_level(tmp_path):
     """`WITH x AS (SELECT 1) DELETE ...` passes a keyword prefix check
     — the read-only guard must hold at the sqlite level."""
