@@ -46,11 +46,7 @@ from jepsen_tpu.checkers.elle.graph import (
 from jepsen_tpu.checkers.elle.specs import CYCLE_ANOMALY_SPECS, SPEC_ORDER
 from jepsen_tpu.history.ir import HistoryIR
 from jepsen_tpu.history.soa import TXN_OK, PackedTxns, pack_txns
-from jepsen_tpu.ops.cycle_sweep import SweepGraph, detect_cycles
-
-# first-call-in-process tracking per jitted stage: telemetry's span
-# attr for "this duration probably includes jit trace+compile"
-_WARM: Dict[str, bool] = {}
+from jepsen_tpu.ops.cycle_sweep import MAX_K_CAP, SweepGraph, detect_cycles
 
 
 def check(history, consistency_models: Sequence[str] = ("serializable",),
@@ -109,9 +105,8 @@ def _check_device(history, consistency_models, anomalies, max_reported,
                                       deadline=deadline, plan=plan)
 
     # phase spans matching the host oracle's stage names (device=True
-    # distinguishes them in one trace); "warm" records whether this
-    # process already traced/compiled the infer program — the closest
-    # cheap proxy for jit compile vs execute time
+    # distinguishes them in one trace); the spans inside a phase each end
+    # at a sync the check makes anyway, so they add no device read
     ph = telemetry.phases()
     ir = history if isinstance(history, HistoryIR) else None
     if isinstance(history, PackedTxns):
@@ -130,12 +125,18 @@ def _check_device(history, consistency_models, anomalies, max_reported,
                 "not": [], "also-not": []}
 
     poll("elle.infer")
-    ph.start("elle.infer", device=True, txns=p.n_txns,
-             warm=_WARM.get("infer", False))
-    _WARM["infer"] = True
-    # the IR caches the padded layout (capacity facts + derived-order
-    # columns): repeat checks over one history skip the pad entirely
-    h = ir.padded("list-append") if ir is not None else pad_packed(p)
+    ph.start("elle.infer", device=True, txns=p.n_txns)
+    with telemetry.span("elle.pad") as sp:
+        # the IR caches the padded layout (capacity facts + derived-order
+        # columns): repeat checks over one history skip the pad entirely
+        h = ir.padded("list-append") if ir is not None else pad_packed(p)
+        if telemetry.enabled():
+            from jepsen_tpu.parallel.batch import _stage_bytes
+
+            sp.set_attr(T=h.txn_type.shape[0], M=h.mop_txn.shape[0],
+                        R=h.rd_elems.shape[0])
+            # `.nbytes` of the padded columns: no device read
+            _stage_bytes(sp, h)
     # sharded-by-default (ISSUE 12): with >1 visible device and a large
     # enough history, op arrays go up with NamedSharding(P("batch")) so
     # GSPMD partitions inference, and each projection sweep runs the
@@ -146,25 +147,22 @@ def _check_device(history, consistency_models, anomalies, max_reported,
     if mesh is not None:
         from jepsen_tpu.parallel.op_shard import shard_padded
 
-        h, _ = shard_padded(h, mesh, "batch")
-    if telemetry.enabled():
-        telemetry.registry().counter("device-bytes-staged").inc(
-            sum(int(np.asarray(a).nbytes) for a in (
-                h.txn_type, h.txn_process, h.txn_invoke_pos,
-                h.txn_complete_pos, h.txn_mask, h.mop_txn, h.mop_kind,
-                h.mop_key, h.mop_val, h.mop_rd_start, h.mop_rd_len,
-                h.mop_mask, h.rd_elems, h.rd_elem_mask)))
+        with telemetry.span("elle.stage") as sp:
+            if telemetry.enabled():
+                sp.set_attr(devices=mesh.devices.size)
+            h, _ = shard_padded(h, mesh, "batch")
     # infer rides the AOT compile cache: shrink probes and campaign
     # cells over same-bucket histories (pad_packed pads to pow2
     # classes) share one executable instead of compiling per shape
     from jepsen_tpu import compilecache
 
-    out = dev("elle.infer",
-              lambda: compilecache.call("elle.infer", infer, h,
-                                        n_keys=h.n_keys))
+    with telemetry.span("elle.infer.run"):
+        out = dev("elle.infer",
+                  lambda: compilecache.call("elle.infer", infer, h,
+                                            n_keys=h.n_keys))
+        counts = {k: int(v) for k, v in out["counts"].items()}
 
     found: Dict[str, List[Any]] = {}
-    counts = {k: int(v) for k, v in out["counts"].items()}
     for name, cnt in counts.items():
         if cnt > 0:
             found[name] = [{"count": cnt}]
@@ -247,29 +245,35 @@ def _check_device(history, consistency_models, anomalies, max_reported,
         if not res.has_cycle:
             continue
         # ---- host classification over witness regions --------------------
-        if host_edges is None:
-            host_edges = _materialize_host_edges(
-                e_src, e_dst, base_mask, rel_of, chains, T)
-        proj = host_edges.project(_expand_rels(rels))
-        regions = _witness_regions(
-            proj, np.asarray(e_src), np.asarray(e_dst), res.witness_edge_ids,
-            2 * T, limit=16)
-        for name, spec in group:
-            hit = None
-            for region in regions:
-                hit = find_cycle(region, proj, _spec_with_chains(spec))
+        with telemetry.span("elle.classify") as sp:
+            if host_edges is None:
+                host_edges = _materialize_host_edges(
+                    e_src, e_dst, base_mask, rel_of, chains, T)
+            proj = host_edges.project(_expand_rels(rels))
+            regions = _witness_regions(
+                proj, np.asarray(e_src), np.asarray(e_dst),
+                res.witness_edge_ids, 2 * T, limit=16)
+            n_found = 0
+            for name, spec in group:
+                hit = None
+                for region in regions:
+                    hit = find_cycle(region, proj, _spec_with_chains(spec))
+                    if hit is not None:
+                        break
                 if hit is not None:
-                    break
-            if hit is not None:
-                if explainer is None:
-                    from jepsen_tpu.checkers.elle.explain import la_explainer
+                    if explainer is None:
+                        from jepsen_tpu.checkers.elle.explain import \
+                            la_explainer
 
-                    explainer = la_explainer(
-                        p, {k: np.asarray(v)
-                            for k, v in out["order"].items()})
-                found.setdefault(name, []).append(
-                    {"cycle": _render(hit, p, T, explainer),
-                     "witnesses": int(len(res.witness_edge_ids))})
+                        explainer = la_explainer(
+                            p, {k: np.asarray(v)
+                                for k, v in out["order"].items()})
+                    found.setdefault(name, []).append(
+                        {"cycle": _render(hit, p, T, explainer),
+                         "witnesses": int(len(res.witness_edge_ids))})
+                    n_found += 1
+            if telemetry.enabled():
+                sp.set_attr(regions=len(regions), found=n_found)
 
     if needs_fallback:
         ph.end()
@@ -279,8 +283,14 @@ def _check_device(history, consistency_models, anomalies, max_reported,
         # pass the ORIGINAL input: an op-level history keeps its session
         # checkability through the fallback (packing drops it); the
         # budget follows — the oracle polls it itself now
-        return oracle.check(history, consistency_models, anomalies,
-                            max_reported=max_reported, deadline=deadline)
+        with telemetry.span("elle.host-fallback") as sp:
+            if telemetry.enabled():
+                sp.set_attr(reason=("max-k-cap" if res.n_backward > MAX_K_CAP
+                                    else "not-converged"),
+                            n_backward=res.n_backward)
+            return oracle.check(history, consistency_models, anomalies,
+                                max_reported=max_reported,
+                                deadline=deadline)
 
     # session-guarantee tokens run the dedicated per-process checker —
     # after the fallback decision, so a non-converged sweep doesn't do
@@ -298,8 +308,13 @@ def _check_device(history, consistency_models, anomalies, max_reported,
     # shared verdict tail (oracle.boundary_verdict): the device pipeline
     # reached this point only with committed txns (the no-ok case early-
     # returned unknown above), so has_ok is True by construction
-    return oracle.boundary_verdict(found, consistency_models, want,
-                                   has_ok=True, sess_checked=sess_checked)
+    with telemetry.span("elle.verdict") as sp:
+        verdict = oracle.boundary_verdict(found, consistency_models, want,
+                                          has_ok=True,
+                                          sess_checked=sess_checked)
+        if telemetry.enabled():
+            sp.set_attr(valid=verdict["valid?"])
+    return verdict
 
 
 def _expand_rels(rels: frozenset) -> Set[int]:

@@ -485,25 +485,38 @@ def detect_cycles(g: SweepGraph, max_k: int = 128,
     # both branches ride the AOT compile cache: verifier sweep chunks
     # and checker projections pad to pow2 (N, E) classes, so
     # maintenance rounds and probes share persisted executables
-    from jepsen_tpu import compilecache
+    from jepsen_tpu import compilecache, telemetry
 
     if mesh is not None and mesh.devices.size > 1:
         n_shards = mesh.shape[axis]
         if max_k % n_shards:
             max_k = ((max_k // n_shards) + 1) * n_shards
-        has, wit, n_back, conv = compilecache.call(
-            "cycle-sweep.sharded", _sweep_sharded_kw, g.rank, g.nc_src,
-            g.nc_dst, g.nc_mask, g.chain_nodes, g.chain_starts,
-            g.chain_mask, n_nodes=g.n_nodes, max_k=max_k,
-            max_rounds=max_rounds, mesh=mesh, axis=axis)
     else:
         mesh = None
-        has, wit, n_back, conv = compilecache.call(
-            "cycle-sweep", _sweep_kw, g.rank, g.nc_src, g.nc_dst,
-            g.nc_mask, g.chain_nodes, g.chain_starts, g.chain_mask,
-            n_nodes=g.n_nodes, max_k=max_k, max_rounds=max_rounds)
-    n_back = int(n_back)
-    if n_back > max_k:
+    # one span per program run, ending at the n_back read that syncs it
+    # (and the converged read the result needs anyway)
+    with telemetry.span("sweep.call") as sp:
+        if mesh is not None:
+            has, wit, n_back, conv = compilecache.call(
+                "cycle-sweep.sharded", _sweep_sharded_kw, g.rank, g.nc_src,
+                g.nc_dst, g.nc_mask, g.chain_nodes, g.chain_starts,
+                g.chain_mask, n_nodes=g.n_nodes, max_k=max_k,
+                max_rounds=max_rounds, mesh=mesh, axis=axis)
+        else:
+            has, wit, n_back, conv = compilecache.call(
+                "cycle-sweep", _sweep_kw, g.rank, g.nc_src, g.nc_dst,
+                g.nc_mask, g.chain_nodes, g.chain_starts, g.chain_mask,
+                n_nodes=g.n_nodes, max_k=max_k, max_rounds=max_rounds)
+        n_back = int(n_back)
+        fits = n_back <= max_k
+        if fits:
+            conv = bool(conv)
+        if telemetry.enabled():
+            sp.set_attr(max_k=max_k, max_rounds=max_rounds,
+                        n_backward=n_back, sharded=mesh is not None)
+            if fits:
+                sp.set_attr(converged=conv)
+    if not fits:
         if n_back > MAX_K_CAP or max_k >= MAX_K_CAP:
             # bit budget unreachable or exhausted (an (n_nodes, max_k)
             # label plane past the cap would chew through memory; and
@@ -520,7 +533,7 @@ def detect_cycles(g: SweepGraph, max_k: int = 128,
                                        MAX_K_CAP),
                              max_rounds=max_rounds, deadline=deadline,
                              mesh=mesh, axis=axis)
-    if not bool(conv) and max_rounds < MAX_ROUNDS_CAP:
+    if not conv and max_rounds < MAX_ROUNDS_CAP:
         # fixpoint truncated: grow rounds like grow_until_exact does for
         # the fused path (histories dense with injected cycles can need
         # hundreds of rounds) before surrendering to the host fallback
@@ -528,18 +541,20 @@ def detect_cycles(g: SweepGraph, max_k: int = 128,
                              max_rounds=min(max_rounds * 2,
                                             MAX_ROUNDS_CAP),
                              deadline=deadline, mesh=mesh, axis=axis)
-    wit = np.asarray(wit)
-    conv = bool(conv)
-    has = bool(has)
-    # map witness backward-edge ids back to edge-array positions
-    mask = np.asarray(g.nc_mask)
-    rank = np.asarray(g.rank)
-    src = np.clip(np.asarray(g.nc_src), 0, g.n_nodes - 1)
-    dst = np.clip(np.asarray(g.nc_dst), 0, g.n_nodes - 1)
-    is_back = mask & (rank[src] >= rank[dst])
-    back_pos = np.nonzero(is_back)[0]
-    wit_ids = back_pos[np.nonzero(wit[:len(back_pos)])[0]] \
-        if len(back_pos) else np.zeros(0, np.int64)
+    with telemetry.span("sweep.witness-map") as sp:
+        wit = np.asarray(wit)
+        has = bool(has)
+        # map witness backward-edge ids back to edge-array positions
+        mask = np.asarray(g.nc_mask)
+        rank = np.asarray(g.rank)
+        src = np.clip(np.asarray(g.nc_src), 0, g.n_nodes - 1)
+        dst = np.clip(np.asarray(g.nc_dst), 0, g.n_nodes - 1)
+        is_back = mask & (rank[src] >= rank[dst])
+        back_pos = np.nonzero(is_back)[0]
+        wit_ids = back_pos[np.nonzero(wit[:len(back_pos)])[0]] \
+            if len(back_pos) else np.zeros(0, np.int64)
+        if telemetry.enabled():
+            sp.set_attr(edges=len(mask), witnesses=len(wit_ids))
     return SweepResult(has_cycle=has, witness_edge_ids=wit_ids,
                        n_backward=n_back, converged=conv)
 

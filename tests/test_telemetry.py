@@ -367,31 +367,150 @@ def test_check_safe_crash_attribution_with_telemetry_enabled():
     assert sp.attrs.get("crashed") is True
 
 
-def test_elle_checker_child_spans(tmp_path):
+def _txn(p, t, mops):
+    from jepsen_tpu.history.ops import Op
+
+    return [Op(type="invoke", process=p, f="txn", value=mops, time=t),
+            Op(type="ok", process=p, f="txn", value=mops, time=t + 1000)]
+
+
+def _traced_la_check(ops):
     from jepsen_tpu.checkers.elle import list_append
-    from jepsen_tpu.history.ops import Op, history
+    from jepsen_tpu.history.ops import history
 
-    def txn(p, t, mops):
-        return [Op(type="invoke", process=p, f="txn", value=mops, time=t),
-                Op(type="ok", process=p, f="txn", value=mops,
-                   time=t + 1000)]
-
-    ops = txn(0, 0, [["append", "x", 1]]) + \
-        txn(1, 5000, [["r", "x", [1]]])
     c = telemetry.activate()
     try:
         with telemetry.span("check:elle"):
             res = list_append.check(history(ops))
     finally:
         telemetry.deactivate(c)
-    assert res["valid?"] is True
     (root,) = c.roots
+    return res, root
+
+
+def _child(sp, name):
+    return next(x for x in sp.children if x.name == name)
+
+
+#: the spans inside one device check of a list-append history
+LA_INNER_SPANS = ("elle.pad", "elle.stage", "elle.infer.run", "sweep.call",
+                  "sweep.witness-map", "elle.classify",
+                  "elle.host-fallback", "elle.verdict")
+
+
+def test_elle_checker_child_spans(tmp_path):
+    res, root = _traced_la_check(_txn(0, 0, [["append", "x", 1]]) +
+                                 _txn(1, 5000, [["r", "x", [1]]]))
+    assert res["valid?"] is True
     names = [s["name"] for s in
              [telemetry.export.span_to_dict(x) for x in root.children]]
     assert "elle.infer" in names
     assert "elle.graph-build" in names and "elle.cycle-sweep" in names
-    infer = next(x for x in root.children if x.name == "elle.infer")
+    infer = _child(root, "elle.infer")
     assert infer.attrs["device"] is True
+    assert "warm" not in infer.attrs
+    # inside inference: the host pad, then the device run (one device:
+    # no reshard onto a mesh)
+    assert [x.name for x in infer.children] == ["elle.pad", "elle.infer.run"]
+    pad = infer.children[0]
+    assert pad.attrs["T"] >= 2 and pad.attrs["bytes_staged"] > 0
+    # one sweep program run and one witness map per projection
+    sweep = _child(root, "elle.cycle-sweep")
+    calls = [x for x in sweep.children if x.name == "sweep.call"]
+    maps = [x for x in sweep.children if x.name == "sweep.witness-map"]
+    assert len(calls) == len(maps) == sweep.attrs["projections"] > 0
+    for c in calls:
+        assert c.attrs["max_k"] == 128 and c.attrs["n_backward"] == 0
+        assert c.attrs["converged"] is True
+        assert c.attrs["sharded"] is False
+    assert all(m.attrs["witnesses"] == 0 for m in maps)
+    assert not any(x.name == "elle.classify" for x in sweep.children)
+    assert names[-1] == "elle.verdict"
+    assert _child(root, "elle.verdict").attrs["valid"] is True
+
+
+def test_elle_stage_span_on_the_sharded_path(monkeypatch):
+    monkeypatch.setenv("JEPSEN_SHARDS", "4")
+    res, root = _traced_la_check(_txn(0, 0, [["append", "x", 1]]) +
+                                 _txn(1, 5000, [["r", "x", [1]]]))
+    assert res["valid?"] is True
+    infer = _child(root, "elle.infer")
+    assert [x.name for x in infer.children] == \
+        ["elle.pad", "elle.stage", "elle.infer.run"]
+    assert infer.children[1].attrs["devices"] == 4
+    calls = [x for x in _child(root, "elle.cycle-sweep").children
+             if x.name == "sweep.call"]
+    assert calls and all(c.attrs["sharded"] is True for c in calls)
+
+
+def test_elle_classify_span_on_a_cycle():
+    # G1c: each txn reads the other's append
+    res, root = _traced_la_check(
+        _txn(0, 0, [["append", "x", 1], ["r", "y", [1]]]) +
+        _txn(1, 10, [["append", "y", 1], ["r", "x", [1]]]))
+    assert res["valid?"] is False and "G1c" in res["anomaly-types"]
+    sweep = _child(root, "elle.cycle-sweep")
+    cls = [x for x in sweep.children if x.name == "elle.classify"]
+    assert cls and all(c.attrs["regions"] >= 1 for c in cls)
+    assert sum(c.attrs["found"] for c in cls) >= 1
+    maps = [x for x in sweep.children if x.name == "sweep.witness-map"]
+    assert max(m.attrs["witnesses"] for m in maps) >= 1
+    assert _child(root, "elle.verdict").attrs["valid"] is False
+
+
+@pytest.mark.parametrize("n_backward,reason", [
+    (0, "not-converged"), (8193, "max-k-cap")])
+def test_elle_host_fallback_span(monkeypatch, n_backward, reason):
+    import dataclasses
+
+    from jepsen_tpu.checkers.elle import list_append
+
+    orig = list_append.detect_cycles
+
+    def not_converged(*a, **kw):
+        return dataclasses.replace(orig(*a, **kw), converged=False,
+                                   n_backward=n_backward)
+
+    monkeypatch.setattr(list_append, "detect_cycles", not_converged)
+    res, root = _traced_la_check(_txn(0, 0, [["append", "x", 1]]) +
+                                 _txn(1, 5000, [["r", "x", [1]]]))
+    assert res["valid?"] is True
+    fb = _child(root, "elle.host-fallback")
+    assert fb.attrs == {"reason": reason, "n_backward": n_backward}
+    assert fb.children  # the host oracle's own phases
+    assert not any(x.name == "elle.verdict" for x in root.children)
+
+
+def test_elle_inner_spans_unrecorded_with_telemetry_off(monkeypatch):
+    from jepsen_tpu.checkers.elle import list_append
+    from jepsen_tpu.history.ops import history
+    from jepsen_tpu.parallel import batch
+    from jepsen_tpu.telemetry import spans
+
+    opened = []
+
+    def span(name, /, **attrs):
+        opened.append(name)
+        return spans._NOOP_SPAN
+
+    def no_bytes(*a):
+        raise AssertionError("staged bytes counted with telemetry off")
+
+    monkeypatch.setattr(telemetry.NOOP, "span", span, raising=False)
+    monkeypatch.setattr(batch, "_stage_bytes", no_bytes)
+    assert telemetry.active() is telemetry.NOOP
+    res = list_append.check(history(
+        _txn(0, 0, [["append", "x", 1], ["r", "y", [1]]]) +
+        _txn(1, 10, [["append", "y", 1], ["r", "x", [1]]])))
+    assert "G1c" in res["anomaly-types"] and "degraded" not in res
+    assert telemetry.active() is telemetry.NOOP
+    assert telemetry.NOOP.roots == []
+    # every inner span was opened on the no-op collector, nothing else
+    assert {"elle.pad", "elle.infer.run", "sweep.call", "sweep.witness-map",
+            "elle.classify", "elle.verdict"} <= set(opened)
+    assert set(opened) - {"elle.pack", "elle.infer", "elle.graph-build",
+                          "elle.cycle-sweep", "elle.sessions"} <= \
+        set(LA_INNER_SPANS)
 
 
 # -------------------------------------------------------------- cli/web
