@@ -46,7 +46,18 @@ from jepsen_tpu.checkers.elle.graph import (
 from jepsen_tpu.checkers.elle.specs import CYCLE_ANOMALY_SPECS, SPEC_ORDER
 from jepsen_tpu.history.ir import HistoryIR
 from jepsen_tpu.history.soa import TXN_OK, PackedTxns, pack_txns
-from jepsen_tpu.ops.cycle_sweep import MAX_K_CAP, SweepGraph, detect_cycles
+from jepsen_tpu.ops.cycle_sweep import (
+    MAX_K_CAP,
+    FamilyGraph,
+    detect_cycles,
+    enumerate_backward,
+)
+
+#: the inferred edge families, in the order the sweep concatenates them,
+#: and the rel of each (tb/bt: the realtime edges into and out of the
+#: barrier nodes)
+FAMILIES = ("ww", "wr", "rw", "tb", "bt")
+FAMILY_RELS = (REL_WW, REL_WR, REL_RW, REL_REALTIME, REL_REALTIME)
 
 
 def check(history, consistency_models: Sequence[str] = ("serializable",),
@@ -187,30 +198,32 @@ def _check_device(history, consistency_models, anomalies, max_reported,
     chains = out["chains"]
     rank = jnp.concatenate([out["ranks"]["txn"], out["ranks"]["barrier"]])
 
-    # static concatenated edge arrays; per-projection masks
-    e_src = jnp.concatenate([edges[k][0] for k in ("ww", "wr", "rw", "tb",
-                                                   "bt")])
-    e_dst = jnp.concatenate([edges[k][1] for k in ("ww", "wr", "rw", "tb",
-                                                   "bt")])
-    sizes = [edges[k][0].shape[0] for k in ("ww", "wr", "rw", "tb", "bt")]
-    rel_of = np.concatenate([
-        np.full(sizes[0], REL_WW), np.full(sizes[1], REL_WR),
-        np.full(sizes[2], REL_RW), np.full(sizes[3], REL_REALTIME),
-        np.full(sizes[4], REL_REALTIME)]).astype(np.int8)
-    base_mask = jnp.concatenate([edges[k][2] for k in ("ww", "wr", "rw",
-                                                       "tb", "bt")])
-    rel_arr = jnp.asarray(rel_of)
+    # static concatenated edge families; each projection keeps whole
+    # families (by rel) and chain groups
+    e_src = jnp.concatenate([edges[k][0] for k in FAMILIES])
+    e_dst = jnp.concatenate([edges[k][1] for k in FAMILIES])
+    sizes = tuple(edges[k][0].shape[0] for k in FAMILIES)
+    rel_of = np.repeat(np.asarray(FAMILY_RELS, np.int8), sizes)
+    base_mask = jnp.concatenate([edges[k][2] for k in FAMILIES])
 
     pc_nodes, pc_starts, pc_mask = chains["process"]
     bc_nodes, bc_starts, bc_mask = chains["barrier"]
-    chain_nodes = jnp.concatenate([pc_nodes, bc_nodes])
-    chain_starts = jnp.concatenate([pc_starts, bc_starts])
+    fam = FamilyGraph(
+        n_nodes=2 * T, rank=rank, nc_src=e_src, nc_dst=e_dst,
+        base_mask=base_mask, fam_lens=sizes,
+        chain_nodes=jnp.concatenate([pc_nodes, bc_nodes]),
+        chain_starts=jnp.concatenate([pc_starts, bc_starts]),
+        chain_masks=(pc_mask, bc_mask))
 
     host_edges: EdgeList = None  # lazily materialized for classification
     explainer = None             # lazily built per-edge Explainer
     needs_fallback = False
     ph.start("elle.cycle-sweep", device=True,
              projections=len(projections))
+    # one backward-edge enumeration of the family union for every
+    # projection's sweep
+    fam = dev("elle.cycle-sweep",
+              lambda: enumerate_backward(fam, mesh=mesh))
     for rels, group in projections.items():
         # deadline poll per projection: the sweep fixpoint retries
         # (grow max_k/max_rounds) can stretch a pathological history —
@@ -226,16 +239,8 @@ def _check_device(history, consistency_models, anomalies, max_reported,
                     **{"anomaly-types": sorted(found),
                        "anomalies": found, "not": [], "also-not": [],
                        "partial": "cycle-sweep interrupted"})
-        sel = jnp.zeros_like(base_mask)
-        for r in rels:
-            sel = sel | (rel_arr == r)
-        mask = base_mask & sel
-        cmask = jnp.concatenate([
-            pc_mask & (REL_PROCESS in rels),
-            bc_mask & (REL_REALTIME in rels)])
-        g = SweepGraph(n_nodes=2 * T, rank=rank, nc_src=e_src, nc_dst=e_dst,
-                       nc_mask=mask, chain_nodes=chain_nodes,
-                       chain_starts=chain_starts, chain_mask=cmask)
+        g = fam.project([int(r in rels) for r in FAMILY_RELS],
+                        [int(REL_PROCESS in rels), int(REL_REALTIME in rels)])
         res = dev("elle.cycle-sweep",
                   lambda g=g: detect_cycles(g, deadline=deadline,
                                             mesh=mesh))

@@ -39,7 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Optional
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -85,8 +85,7 @@ def backward_test(rank, nc_src, nc_dst, n_nodes: int):
 def _sweep_window(n_nodes: int, k_total: int, k_local: int, max_rounds: int,
                   rank, nc_src, nc_dst, nc_mask,
                   chain_nodes, chain_starts, chain_mask,
-                  k_offset, axis_name=None, back_raw=None, back_pre=None,
-                  back_tables=None):
+                  k_offset, axis_name=None, back_pre=None, back_tables=None):
     """Sweep kernel over a window of the backward-edge axis.
 
     Each caller owns backward edges with global ids in
@@ -104,38 +103,28 @@ def _sweep_window(n_nodes: int, k_total: int, k_local: int, max_rounds: int,
     """
     # ---- split edges: backward iff rank[src] >= rank[dst] -----------------
     # (chain edges are forward by construction: caller guarantees ranks
-    # increase along chains).  `back_raw` lets a caller scanning over
-    # several projections hoist the two E-sized rank gathers out of the
-    # scan — the comparison is projection-independent, only the mask
-    # varies (1 byte/edge hoisted vs 8 bytes/edge re-gathered 5x).
+    # increase along chains)
     if back_pre is not None:
-        # caller hoisted the whole backward enumeration (is_back,
-        # position-stable back_id, n_back) — e.g. device_core's
-        # projection scan, which derives them from ONE shared cumsum
-        # plus per-family offsets instead of an E-sized cumsum per
-        # projection.  Must be bit-identical to the block below.
-        is_back, back_id, n_back = back_pre
+        # caller hoisted the backward enumeration: (is_back, n_back), and
+        # the (k_total,) endpoint tables `back_tables` that come with it.
+        # `project_families` reads both off ONE enumeration of the family
+        # union, where each projection ran a rank test, an E-sized cumsum
+        # and the two E-sized scatter-max reductions below (on TPU the
+        # scatters measured 2.4 s/run at 1M shapes: 0.24 s x 2 x 5
+        # projections, ~24% of the whole check).  Must be bit-identical
+        # to the block below.
+        is_back, n_back = back_pre
+        bsrc_full, bdst_full = back_tables
+        bdst_local = jax.lax.dynamic_slice(
+            bdst_full, (k_offset,), (k_local,))
     else:
-        if back_raw is None:
-            back_raw = backward_test(rank, nc_src, nc_dst, n_nodes)
-        is_back = nc_mask & back_raw
+        is_back = nc_mask & backward_test(rank, nc_src, nc_dst, n_nodes)
         n_back = jnp.sum(is_back.astype(jnp.int32))
 
         # stable enumeration of backward edges: order by edge position
         back_order = jnp.cumsum(is_back.astype(jnp.int32)) - 1
         back_id = jnp.where(is_back, back_order, -1)
 
-    if back_tables is not None:
-        # caller supplied the (k_total,) backward-edge endpoint tables
-        # (projection_scan builds them with ~k binary searches over its
-        # ONE shared cumsum) — skip the two E-sized scatter-max
-        # reductions below entirely.  On TPU those scatters measured
-        # 2.4 s/run at 1M shapes (0.24 s x 2 x 5 projections, ~24% of
-        # the whole check); the searchsorted tables are microseconds.
-        bsrc_full, bdst_full = back_tables
-        bdst_local = jax.lax.dynamic_slice(
-            bdst_full, (k_offset,), (k_local,))
-    else:
         # full-width source table (identical on every window — needed
         # for the meta-graph columns)
         in_full = is_back & (back_id < k_total)
@@ -240,8 +229,8 @@ def _sweep_window(n_nodes: int, k_total: int, k_local: int, max_rounds: int,
 
 def _sweep_arrays(n_nodes: int, max_k: int, max_rounds: int,
                   rank, nc_src, nc_dst, nc_mask,
-                  chain_nodes, chain_starts, chain_mask, back_raw=None,
-                  back_pre=None, back_tables=None):
+                  chain_nodes, chain_starts, chain_mask, back_pre=None,
+                  back_tables=None):
     """Core kernel (single window).  Returns (has_cycle, witness_bits,
     n_backward, converged).
 
@@ -254,8 +243,7 @@ def _sweep_arrays(n_nodes: int, max_k: int, max_rounds: int,
                          rank, nc_src, nc_dst, nc_mask,
                          chain_nodes, chain_starts, chain_mask,
                          k_offset=jnp.int32(0), axis_name=None,
-                         back_raw=back_raw, back_pre=back_pre,
-                         back_tables=back_tables)
+                         back_pre=back_pre, back_tables=back_tables)
 
 
 _sweep = jax.jit(_sweep_arrays,
@@ -312,6 +300,95 @@ def _sweep_sharded_kw(rank, nc_src, nc_dst, nc_mask, chain_nodes,
                           chain_starts, chain_mask)
 
 
+def _per_edge(vals, fam_lens):
+    """Per-family values broadcast over their concatenated edge blocks."""
+    return jnp.concatenate([jnp.broadcast_to(vals[f], (L,))
+                            for f, L in enumerate(fam_lens)])
+
+
+def enumerate_families(n_nodes: int, k_tab: int, fam_lens,
+                       rank, e_src, e_dst, union_mask):
+    """The backward-edge enumeration of a union of edge families: ONE
+    rank test and ONE E-sized cumsum for every projection that keeps
+    whole families (the single source `projection_scan` and the
+    list-append sweep both call; `project_families` reads one
+    projection off it).
+
+    Families are concatenated blocks of `fam_lens` edges.  `cum` steps
+    by exactly 1 at each union-masked backward edge, so family f's j-th
+    backward edge (in edge order) is the first position of f's block
+    where `cum` reaches cum_start[f] + j + 1: k_tab binary searches per
+    family build the endpoint tables, where a scatter-max over every
+    edge did before (0.24 s each per projection at 1M-txn TPU shapes).
+
+    Returns (back_all (E,) bool, count_f (F,) int32, fam_src, fam_dst
+    (F, k_tab) int32), the tables 0 past count_f[f].
+    """
+    bounds = np.cumsum([0] + list(fam_lens))
+    back_all = union_mask & backward_test(rank, e_src, e_dst, n_nodes)
+    cum = jnp.cumsum(back_all.astype(jnp.int32))
+    cum_start = [cum[int(b) - 1] if b > 0 else jnp.int32(0)
+                 for b in bounds[:-1]]
+    count_f = jnp.stack([
+        (cum[int(e) - 1] if e > 0 else jnp.int32(0)) - s
+        for s, e in zip(cum_start, bounds[1:])])
+    j = jnp.arange(k_tab, dtype=jnp.int32)
+    srcs, dsts = [], []
+    for f, L in enumerate(fam_lens):
+        if L == 0:
+            srcs.append(jnp.zeros((k_tab,), jnp.int32))
+            dsts.append(jnp.zeros((k_tab,), jnp.int32))
+            continue
+        lo, hi = int(bounds[f]), int(bounds[f + 1])
+        pos = lo + jnp.searchsorted(cum[lo:hi], cum_start[f] + j + 1,
+                                    side="left").astype(jnp.int32)
+        pos = jnp.clip(pos, 0, cum.shape[0] - 1)
+        ok = j < count_f[f]
+        srcs.append(jnp.where(ok, e_src[pos], 0))
+        dsts.append(jnp.where(ok, e_dst[pos], 0))
+    return back_all, count_f, jnp.stack(srcs), jnp.stack(dsts)
+
+
+def project_families(fam_lens, max_k: int, union_mask, chain_masks,
+                     enumeration, inc, cinc):
+    """One projection of a family union, read off the union's
+    enumeration (`enumerate_families`) with no E-sized gather, scan or
+    scatter: the masks and the hoisted backward enumeration
+    `_sweep_window` takes.
+
+    The projection keeps edge family f whole iff inc[f] > 0 and chain
+    group g iff cinc[g] > 0.  Its family-f backward set IS the union's
+    (family masks don't vary per projection, only inclusion), so its
+    position-stable enumeration is each kept family's, shifted by the
+    counts of the kept families before it: bit-identical to a cumsum
+    and scatter-max over the projection's own mask (ids >= n_back stay
+    0).  Returns (nc_mask (E,), chain_mask (C,), back_pre (is_back,
+    n_back), back_tables (bsrc, bdst) (max_k,)).
+    """
+    back_all, count_f, fam_src, fam_dst = enumeration
+    k_tab = fam_src.shape[1]
+    if k_tab < max_k:
+        raise ValueError(f"endpoint tables of {k_tab} < max_k {max_k}")
+    keep = _per_edge(inc > 0, fam_lens)
+    chain_mask = jnp.concatenate([m & (cinc[g] > 0)
+                                  for g, m in enumerate(chain_masks)])
+    offs = jnp.concatenate(
+        [jnp.zeros(1, jnp.int32), jnp.cumsum(count_f * inc)[:-1]])
+    tgt = jnp.arange(max_k, dtype=jnp.int32)
+    bsrc = jnp.zeros((max_k,), jnp.int32)
+    bdst = jnp.zeros((max_k,), jnp.int32)
+    for f, L in enumerate(fam_lens):
+        if L == 0:
+            continue
+        j = tgt - offs[f]
+        sel = (inc[f] > 0) & (j >= 0) & (j < count_f[f])
+        jc = jnp.clip(j, 0, k_tab - 1)
+        bsrc = jnp.where(sel, fam_src[f, jc], bsrc)
+        bdst = jnp.where(sel, fam_dst[f, jc], bdst)
+    return (union_mask & keep, chain_mask,
+            (back_all & keep, jnp.sum(count_f * inc)), (bsrc, bdst))
+
+
 def projection_scan(n_nodes: int, max_k: int, max_rounds: int,
                     rank, e_src, e_dst, fam_masks, inc_stack,
                     chain_nodes, chain_starts, chain_masks, cinc_stack,
@@ -323,24 +400,22 @@ def projection_scan(n_nodes: int, max_k: int, max_rounds: int,
     `sweep` (optional) replaces the single-window `_sweep_arrays` call
     with a caller-supplied kernel of signature (rank, e_src, e_dst,
     mask, chain_nodes, chain_starts, chain_mask, back_pre,
-    back_tables) -> (has, witness, n_back, converged), where
-    back_tables is the (max_k,) (bsrc, bdst) endpoint pair built here
-    by binary search — how the K-windowed sharded paths
-    (`parallel/op_shard.py`, `parallel/hybrid.py`) reuse this scan with
-    `_sweep_window` inside shard_map while keeping the hoisted
-    enumeration (VERDICT r04 item 2: the sharded sweep previously
-    re-materialized (5, E) mask stacks and ran 5 E-sized cumsums).
+    back_tables) -> (has, witness, n_back, converged), where back_pre
+    is (is_back, n_back) and back_tables the (max_k,) (bsrc, bdst)
+    endpoint pair of `project_families` — how the K-windowed sharded
+    paths (`parallel/op_shard.py`, `parallel/hybrid.py`) reuse this
+    scan with `_sweep_window` inside shard_map while keeping the
+    hoisted enumeration (VERDICT r04 item 2: the sharded sweep
+    previously re-materialized (5, E) mask stacks and ran 5 E-sized
+    cumsums).
 
     Instead of materialized (P, E)/(P, C) mask stacks and an E-sized
     cumsum per projection, the scan consumes tiny include matrices:
-    per-projection masks are `family_mask & include`, and backward-edge
-    enumeration hoists to ONE shared cumsum + per-family count offsets.
-    Families are concatenated blocks, so a projection's position-stable
-    enumeration equals its within-family ids shifted by the counts of
-    its included predecessor families — bit-identical to cumsum over
-    the projection's own mask (the `back_pre` path in `_sweep_window`).
-    Measured effect at 1M txns on CPU: fused check 7.98 s -> 5.18 s and
-    compile 28.8 s -> 7.9 s (PROFILE.md §0b).
+    per-projection masks are `family_mask & include`, and the
+    backward-edge enumeration is `enumerate_families`' ONE, read per
+    projection by `project_families`.  Measured effect at 1M txns on
+    CPU: fused check 7.98 s -> 5.18 s and compile 28.8 s -> 7.9 s
+    (PROFILE.md §0b).
 
     fam_masks: per-family (E_f,) bool masks, concat order == e_src.
     inc_stack: (P, F) int32 — family f included in projection p.
@@ -349,75 +424,25 @@ def projection_scan(n_nodes: int, max_k: int, max_rounds: int,
     Returns (conv_all, overflow, cyc_bits (P,) int32).
     """
     fam_lens = [int(m.shape[0]) for m in fam_masks]
-    bounds = np.cumsum([0] + fam_lens)
     union_mask = jnp.concatenate(list(fam_masks))
-
-    back_raw = backward_test(rank, e_src, e_dst, n_nodes)
-    back_all = union_mask & back_raw
-    cum = jnp.cumsum(back_all.astype(jnp.int32))             # ONE E-cumsum
-    cum_start = [cum[int(b) - 1] if b > 0 else jnp.int32(0)
-                 for b in bounds[:-1]]
-    count_f = jnp.stack([
-        (cum[int(e) - 1] if e > 0 else jnp.int32(0)) - s
-        for s, e in zip(cum_start, bounds[1:])])
-    within = (cum - 1) - jnp.concatenate(
-        [jnp.broadcast_to(s, (L,)) for s, L in zip(cum_start, fam_lens)])
-
-    def rep(valsF):
-        return jnp.concatenate(
-            [jnp.broadcast_to(valsF[i], (L,))
-             for i, L in enumerate(fam_lens)])
+    enum = enumerate_families(n_nodes, max_k, fam_lens, rank, e_src, e_dst,
+                              union_mask)
+    count_f = enum[1]
 
     def proj_body(carry, mc):
         conv_all, overflow = carry
         inc, cinc = mc
-        inc_b = inc.astype(bool)
-        m = union_mask & rep(inc_b)
-        cm = jnp.concatenate([cmask & (cinc[g] > 0)
-                              for g, cmask in enumerate(chain_masks)])
-        offs = jnp.concatenate(
-            [jnp.zeros(1, jnp.int32), jnp.cumsum(count_f * inc)[:-1]])
-        is_back = back_all & rep(inc_b)
-        back_id = jnp.where(is_back, within + rep(offs), -1)
-        n_back = jnp.sum(count_f * inc)
-
-        # (max_k,) backward-edge endpoint tables via binary search over
-        # the shared cumsum instead of E-sized scatter-max in the sweep
-        # (the scatters measured 0.24 s each per projection at 1M-txn
-        # TPU shapes — ~24% of the whole check).  The edge with
-        # projection id i of family f is the first position in f's
-        # block where `cum` reaches cum_start[f] + (i - offs[f]) + 1:
-        # cum steps by exactly 1 at each union-masked backward edge,
-        # and a projection's family-f backward set IS the union's
-        # (family masks don't vary per projection, only inclusion).
-        # Bit-identical to the scatter form: unique ids -> the single
-        # contributing edge's endpoint; ids >= n_back stay 0.
-        tgt = jnp.arange(max_k, dtype=jnp.int32)
-        bsrc_k = jnp.zeros((max_k,), jnp.int32)
-        bdst_k = jnp.zeros((max_k,), jnp.int32)
-        for f, L in enumerate(fam_lens):
-            if L == 0:
-                continue
-            lo, hi = int(bounds[f]), int(bounds[f + 1])
-            j = tgt - offs[f]
-            pos = lo + jnp.searchsorted(
-                cum[lo:hi], cum_start[f] + j + 1,
-                side="left").astype(jnp.int32)
-            pos = jnp.clip(pos, 0, cum.shape[0] - 1)
-            sel = inc_b[f] & (j >= 0) & (j < count_f[f])
-            bsrc_k = jnp.where(sel, e_src[pos], bsrc_k)
-            bdst_k = jnp.where(sel, e_dst[pos], bdst_k)
-
+        m, cm, back_pre, tables = project_families(
+            fam_lens, max_k, union_mask, chain_masks, enum, inc, cinc)
         if sweep is None:
             has, _, n_back_out, conv = _sweep_arrays(
                 n_nodes, max_k, max_rounds, rank, e_src, e_dst, m,
-                chain_nodes, chain_starts, cm,
-                back_pre=(is_back, back_id, n_back),
-                back_tables=(bsrc_k, bdst_k))
+                chain_nodes, chain_starts, cm, back_pre=back_pre,
+                back_tables=tables)
         else:
             has, _, n_back_out, conv = sweep(
                 rank, e_src, e_dst, m, chain_nodes, chain_starts, cm,
-                (is_back, back_id, n_back), (bsrc_k, bdst_k))
+                back_pre, tables)
         carry = (conv_all & conv,
                  jnp.maximum(overflow,
                              jnp.maximum(n_back_out - max_k, 0)))
@@ -443,8 +468,7 @@ def projection_scan(n_nodes: int, max_k: int, max_rounds: int,
         # old cost, never a new one.)
         return zero0 == 0, zero0, jnp.zeros((n_proj,), jnp.int32) + zero0
 
-    total_back = cum[-1] if cum.shape[0] else jnp.int32(0)
-    return jax.lax.cond(total_back > 0, run_scan, no_backward,
+    return jax.lax.cond(jnp.sum(count_f) > 0, run_scan, no_backward,
                         operand=None)
 
 #: budget ceilings shared by every sweep driver (detect_cycles here,
@@ -455,6 +479,181 @@ MAX_ROUNDS_CAP = 1024
 
 
 @dataclasses.dataclass
+class FamilyGraph:
+    """A sweep graph whose projections each keep whole edge families and
+    chain groups (device arrays): the list-append checker's, whose
+    projections are sets of dependency rels.
+
+    Non-chain edges are families concatenated in blocks of `fam_lens`
+    edges under one `base_mask`; chains are groups concatenated in
+    `chain_nodes` order, one mask each.  `enumerate_backward` makes the
+    union's backward-edge enumeration once (`enumeration`); each
+    `project(...)` is then swept by `detect_cycles` with no E-sized
+    rank test, cumsum or scatter of its own.
+    """
+
+    n_nodes: int
+    rank: jnp.ndarray                   # (N,) int32, unique
+    nc_src: jnp.ndarray                 # (E,) int32, families concatenated
+    nc_dst: jnp.ndarray                 # (E,) int32
+    base_mask: jnp.ndarray              # (E,) bool
+    fam_lens: Tuple[int, ...]
+    chain_nodes: jnp.ndarray            # (C,) int32
+    chain_starts: jnp.ndarray           # (C,) bool
+    chain_masks: Tuple[jnp.ndarray, ...]  # per group (C_g,) bool
+    #: (back_all, count_f, fam_src, fam_dst) of `enumerate_families`
+    enumeration: Optional[tuple] = None
+
+    @property
+    def k_tab(self) -> int:
+        """Edges a family in the endpoint tables (0: not enumerated)."""
+        return self.enumeration[2].shape[1] if self.enumeration else 0
+
+    def project(self, inc: Sequence[int],
+                cinc: Sequence[int]) -> "FamilyProjection":
+        """The projection keeping family f iff inc[f] and chain group g
+        iff cinc[g]."""
+        return FamilyProjection(self, tuple(inc), tuple(cinc))
+
+
+@dataclasses.dataclass
+class FamilyProjection:
+    """One projection of an enumerated `FamilyGraph`: the edge families
+    (`inc`) and chain groups (`cinc`) it keeps, 1 or 0 each."""
+
+    graph: FamilyGraph
+    inc: Tuple[int, ...]
+    cinc: Tuple[int, ...]
+
+    def host_mask(self) -> np.ndarray:
+        """The projection's edge mask on the host (for the witness map)."""
+        mask = np.array(self.graph.base_mask)
+        bounds = np.cumsum((0,) + tuple(self.graph.fam_lens))
+        for lo, hi, keep in zip(bounds[:-1], bounds[1:], self.inc):
+            if not keep:
+                mask[lo:hi] = False
+        return mask
+
+
+@partial(jax.jit, static_argnames=("n_nodes", "k_tab", "fam_lens", "mesh"))
+def _enumerate_kw(rank, nc_src, nc_dst, base_mask, *, n_nodes, k_tab,
+                  fam_lens, mesh=None):
+    def run(*a):
+        return enumerate_families(n_nodes, k_tab, fam_lens, *a)
+
+    if mesh is not None:
+        # every chip enumerates the whole union, so what each projection's
+        # shard_map sweep reads goes in replicated
+        from jax.sharding import PartitionSpec as P
+
+        run = jax.shard_map(run, mesh=mesh, in_specs=(P(),) * 4,
+                            out_specs=(P(),) * 4)
+    return run(rank, nc_src, nc_dst, base_mask)
+
+
+def enumerate_backward(g: FamilyGraph, k_tab: int = 128, mesh=None,
+                       axis: str = "batch") -> FamilyGraph:
+    """`g` with its union's backward-edge enumeration made, endpoint
+    tables of `k_tab` edges a family (rounded up to the mesh, as
+    `detect_cycles` rounds max_k): one program (span `sweep.enumerate`)
+    for all of its projections' sweeps.  `detect_cycles` enumerates
+    again, as wide as its `max_k`, only for a retry that outgrows the
+    tables: MAX_K_CAP-wide tables up front would make every check pay
+    for binary searches that only a retry reads (about 6 ms of a 52 ms
+    enumeration on a v5e at 2^18 txns)."""
+    from jepsen_tpu import compilecache, telemetry
+
+    if mesh is not None and mesh.devices.size <= 1:
+        mesh = None
+    if mesh is not None:
+        k_tab = -(-k_tab // mesh.shape[axis]) * mesh.shape[axis]
+    with telemetry.span("sweep.enumerate") as sp:
+        enum = compilecache.call(
+            "cycle-sweep.enumerate", _enumerate_kw, g.rank, g.nc_src,
+            g.nc_dst, g.base_mask, n_nodes=g.n_nodes, k_tab=k_tab,
+            fam_lens=tuple(g.fam_lens), mesh=mesh)
+        if telemetry.enabled():
+            # the read ends the span at the program's end
+            sp.set_attr(n_backward_union=int(np.asarray(enum[1]).sum()),
+                        edges=int(g.nc_src.shape[0]), k_tab=k_tab,
+                        sharded=mesh is not None)
+    return dataclasses.replace(g, enumeration=tuple(enum))
+
+
+def _sweep_families(n_nodes, max_k, k_local, max_rounds, fam_lens, rank,
+                    nc_src, nc_dst, base_mask, enumeration, inc, chain_nodes,
+                    chain_starts, chain_masks, cinc, k_offset,
+                    axis_name=None):
+    """`_sweep_window` over one projection of a `FamilyGraph`, read off
+    the union's enumeration by `project_families`: only O(max_k) and
+    elementwise work before the `n_back > 0` cond."""
+    nc_mask, chain_mask, back_pre, tables = project_families(
+        fam_lens, max_k, base_mask, chain_masks, enumeration, inc, cinc)
+    return _sweep_window(n_nodes, max_k, k_local, max_rounds, rank, nc_src,
+                         nc_dst, nc_mask, chain_nodes, chain_starts,
+                         chain_mask, k_offset, axis_name=axis_name,
+                         back_pre=back_pre, back_tables=tables)
+
+
+@partial(jax.jit, static_argnames=("n_nodes", "max_k", "max_rounds",
+                                   "fam_lens", "mesh", "axis"))
+def _sweep_families_kw(rank, nc_src, nc_dst, base_mask, enumeration, inc,
+                       chain_nodes, chain_starts, chain_masks, cinc, *,
+                       n_nodes, max_k, max_rounds, fam_lens, mesh=None,
+                       axis=None):
+    """One projection's sweep: single-window, or with `mesh` the
+    backward-edge axis sharded over it as `_sweep_sharded` does, every
+    input replicated."""
+    args = (rank, nc_src, nc_dst, base_mask, enumeration, inc, chain_nodes,
+            chain_starts, chain_masks, cinc)
+    if mesh is None:
+        return _sweep_families(n_nodes, max_k, max_k, max_rounds, fam_lens,
+                               *args, k_offset=jnp.int32(0))
+    from jax.sharding import PartitionSpec as P
+
+    n_shards = mesh.shape[axis]
+    assert max_k % n_shards == 0, (max_k, n_shards)
+    k_local = max_k // n_shards
+
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(),) * len(args),
+             out_specs=(P(),) * 4)
+    def run(*a):
+        off = jax.lax.axis_index(axis) * k_local
+        return _sweep_families(n_nodes, max_k, k_local, max_rounds,
+                               fam_lens, *a, k_offset=off, axis_name=axis)
+
+    return run(*args)
+
+
+def _run_sweep(g, max_k: int, max_rounds: int, mesh, axis: str):
+    """Dispatch one sweep program through the AOT compile cache: verifier
+    sweep chunks and checker projections pad to pow2 (N, E) classes, so
+    maintenance rounds and probes share persisted executables."""
+    from jepsen_tpu import compilecache
+
+    if isinstance(g, FamilyProjection):
+        u = g.graph
+        return compilecache.call(
+            "cycle-sweep.families", _sweep_families_kw, u.rank, u.nc_src,
+            u.nc_dst, u.base_mask, u.enumeration,
+            jnp.asarray(g.inc, jnp.int32), u.chain_nodes, u.chain_starts,
+            tuple(u.chain_masks), jnp.asarray(g.cinc, jnp.int32),
+            n_nodes=u.n_nodes, max_k=max_k, max_rounds=max_rounds,
+            fam_lens=tuple(u.fam_lens), mesh=mesh,
+            axis=axis if mesh is not None else None)
+    if mesh is not None:
+        return compilecache.call(
+            "cycle-sweep.sharded", _sweep_sharded_kw, g.rank, g.nc_src,
+            g.nc_dst, g.nc_mask, g.chain_nodes, g.chain_starts,
+            g.chain_mask, n_nodes=g.n_nodes, max_k=max_k,
+            max_rounds=max_rounds, mesh=mesh, axis=axis)
+    return compilecache.call(
+        "cycle-sweep", _sweep_kw, g.rank, g.nc_src, g.nc_dst, g.nc_mask,
+        g.chain_nodes, g.chain_starts, g.chain_mask, n_nodes=g.n_nodes,
+        max_k=max_k, max_rounds=max_rounds)
+
+
+@dataclasses.dataclass
 class SweepResult:
     has_cycle: bool
     witness_edge_ids: np.ndarray  # indices into the non-chain edge arrays
@@ -462,7 +661,7 @@ class SweepResult:
     converged: bool
 
 
-def detect_cycles(g: SweepGraph, max_k: int = 128,
+def detect_cycles(g: SweepGraph | FamilyProjection, max_k: int = 128,
                   max_rounds: int = 64, deadline=None, mesh=None,
                   axis: str = "batch") -> SweepResult:
     """Run the sweep; rebatch automatically if backward edges exceed max_k.
@@ -470,6 +669,11 @@ def detect_cycles(g: SweepGraph, max_k: int = 128,
     Exact: cycle reported iff one exists in the (masked) graph, provided
     converged=True.  Witnesses identify backward edges on cycles (for the
     first max_k; enough to hand the host a subgraph to classify).
+
+    `g` is a plain `SweepGraph`, swept by a program that enumerates its
+    backward edges itself, or a `FamilyProjection` of a `FamilyGraph`
+    enumerated on the same `mesh` (`enumerate_backward`), whose program
+    reads them off the union's enumeration.  Both give the same result.
 
     `deadline` (a `resilience.Deadline`) is polled before each grow-
     retry — the budget-doubling fixpoint is this driver's unbounded
@@ -482,10 +686,7 @@ def detect_cycles(g: SweepGraph, max_k: int = 128,
     """
     if deadline is not None:
         deadline.check("cycle-sweep")
-    # both branches ride the AOT compile cache: verifier sweep chunks
-    # and checker projections pad to pow2 (N, E) classes, so
-    # maintenance rounds and probes share persisted executables
-    from jepsen_tpu import compilecache, telemetry
+    from jepsen_tpu import telemetry
 
     if mesh is not None and mesh.devices.size > 1:
         n_shards = mesh.shape[axis]
@@ -493,20 +694,14 @@ def detect_cycles(g: SweepGraph, max_k: int = 128,
             max_k = ((max_k // n_shards) + 1) * n_shards
     else:
         mesh = None
+    if isinstance(g, FamilyProjection) and g.graph.k_tab < max_k:
+        # a retry outgrew the union's endpoint tables: enumerate again
+        g = dataclasses.replace(
+            g, graph=enumerate_backward(g.graph, max_k, mesh, axis))
     # one span per program run, ending at the n_back read that syncs it
     # (and the converged read the result needs anyway)
     with telemetry.span("sweep.call") as sp:
-        if mesh is not None:
-            has, wit, n_back, conv = compilecache.call(
-                "cycle-sweep.sharded", _sweep_sharded_kw, g.rank, g.nc_src,
-                g.nc_dst, g.nc_mask, g.chain_nodes, g.chain_starts,
-                g.chain_mask, n_nodes=g.n_nodes, max_k=max_k,
-                max_rounds=max_rounds, mesh=mesh, axis=axis)
-        else:
-            has, wit, n_back, conv = compilecache.call(
-                "cycle-sweep", _sweep_kw, g.rank, g.nc_src, g.nc_dst,
-                g.nc_mask, g.chain_nodes, g.chain_starts, g.chain_mask,
-                n_nodes=g.n_nodes, max_k=max_k, max_rounds=max_rounds)
+        has, wit, n_back, conv = _run_sweep(g, max_k, max_rounds, mesh, axis)
         n_back = int(n_back)
         fits = n_back <= max_k
         if fits:
@@ -545,10 +740,13 @@ def detect_cycles(g: SweepGraph, max_k: int = 128,
         wit = np.asarray(wit)
         has = bool(has)
         # map witness backward-edge ids back to edge-array positions
-        mask = np.asarray(g.nc_mask)
-        rank = np.asarray(g.rank)
-        src = np.clip(np.asarray(g.nc_src), 0, g.n_nodes - 1)
-        dst = np.clip(np.asarray(g.nc_dst), 0, g.n_nodes - 1)
+        if isinstance(g, FamilyProjection):
+            mask, base = g.host_mask(), g.graph
+        else:
+            mask, base = np.asarray(g.nc_mask), g
+        rank = np.asarray(base.rank)
+        src = np.clip(np.asarray(base.nc_src), 0, base.n_nodes - 1)
+        dst = np.clip(np.asarray(base.nc_dst), 0, base.n_nodes - 1)
         is_back = mask & (rank[src] >= rank[dst])
         back_pos = np.nonzero(is_back)[0]
         wit_ids = back_pos[np.nonzero(wit[:len(back_pos)])[0]] \

@@ -62,8 +62,8 @@ def projection_sweep_bits(out, max_k: int, sweep):
     callable (rank, e_src, e_dst, mask, chain_nodes, chain_starts,
     chain_mask, back_pre, back_tables) -> (has_cycle, witness, n_back,
     converged); back_pre is the hoisted backward enumeration (is_back,
-    back_id, n_back) and back_tables the searchsorted-built (max_k,)
-    (bsrc, bdst) endpoint pair that `_sweep_window` consumes directly.
+    n_back) and back_tables the searchsorted-built (max_k,) (bsrc,
+    bdst) endpoint pair that `_sweep_window` consumes directly.
 
     One sweep instantiation scanned over the 5 projections — same
     compile-time + label-plane-memory rationale as device_core.core_check
@@ -125,14 +125,14 @@ def _core_check_sharded(h: PaddedLA, n_keys: int, mesh: Mesh, axis: str,
     rep = P()
 
     @partial(jax.shard_map, mesh=mesh,
-             in_specs=(rep,) * 12, out_specs=(rep, rep, rep, rep))
+             in_specs=(rep,) * 11, out_specs=(rep, rep, rep, rep))
     def sharded_sweep(rank_, e_src_, e_dst_, m_, cn_, cs_, cm_,
-                      ib_, bid_, nb_, bsrc_, bdst_):
+                      ib_, nb_, bsrc_, bdst_):
         off = jax.lax.axis_index(axis) * k_local
         return _sweep_window(2 * T, max_k, k_local, max_rounds,
                              rank_, e_src_, e_dst_, m_, cn_, cs_, cm_,
                              k_offset=off, axis_name=axis,
-                             back_pre=(ib_, bid_, nb_),
+                             back_pre=(ib_, nb_),
                              back_tables=(bsrc_, bdst_))
 
     return projection_sweep_bits(

@@ -393,9 +393,9 @@ def _child(sp, name):
 
 
 #: the spans inside one device check of a list-append history
-LA_INNER_SPANS = ("elle.pad", "elle.stage", "elle.infer.run", "sweep.call",
-                  "sweep.witness-map", "elle.classify",
-                  "elle.host-fallback", "elle.verdict")
+LA_INNER_SPANS = ("elle.pad", "elle.stage", "elle.infer.run",
+                  "sweep.enumerate", "sweep.call", "sweep.witness-map",
+                  "elle.classify", "elle.host-fallback", "elle.verdict")
 
 
 def test_elle_checker_child_spans(tmp_path):
