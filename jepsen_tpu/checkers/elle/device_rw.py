@@ -380,43 +380,51 @@ def check(p: PackedTxns | PaddedLA, n_keys: int = None, max_k: int = 128,
 
     from jepsen_tpu import resilience, telemetry
 
-    h = p if isinstance(p, PaddedLA) else pad_packed(p)
+    # one phase span over the whole device check, the host pad and every
+    # grow-retry included (inference and sweeps are fused in one jit
+    # program, so inside it only the pad and each program run are spans)
+    ph = telemetry.phases()
+    ph.start("elle.rw-core-check", device=True)
+    with telemetry.span("elle.pad") as sp:
+        h = p if isinstance(p, PaddedLA) else pad_packed(p)
+        sp.set_attr(T=h.txn_type.shape[0], M=h.mop_txn.shape[0],
+                    V=h.rd_elems.shape[0])
     n_keys = h.n_keys if n_keys is None else n_keys
     rw_cap = h.mop_txn.shape[0]
-
-    # one phase span over the whole fused check incl. grow-retries
-    # (infer/graph-build/cycle-sweep are fused in one jit program here,
-    # so per-stage child spans would only time dispatch)
-    ph = telemetry.phases()
-    ph.start("elle.rw-core-check", device=True,
-             t_pad=h.txn_type.shape[0])
 
     while True:
         if deadline is not None:
             deadline.check("elle.rw-core-check")
-        bits, over, rw_over = resilience.device_call(
-            "elle.rw-core-check",
-            lambda: _cc_call(h, n_keys, max_k, max_rounds, rw_cap),
-            policy=policy, deadline=deadline, plan=plan)
-        over_i = int(np.asarray(over))
-        rw_over_i = int(np.asarray(rw_over))
-        conv = int(np.asarray(bits)[-1]) == 1
-        if rw_over_i > 0 and rw_cap < RW_CAP_LIMIT:
-            need = min(rw_cap + rw_over_i, RW_CAP_LIMIT)
-            while rw_cap < need:
-                rw_cap *= 2
-            rw_cap = min(rw_cap, RW_CAP_LIMIT)
-            continue
-        if over_i > 0 and max_k < MAX_K_CAP:
-            need = max_k + over_i
-            while max_k < need:
-                max_k *= 2
-            max_k = min(max_k, MAX_K_CAP)
-            continue
-        if not conv and over_i == 0 and max_rounds < MAX_ROUNDS_CAP:
-            max_rounds = min(max_rounds * 2, MAX_ROUNDS_CAP)
-            continue
-        break
+        # one span per program run, ending at the reads the loop makes
+        with telemetry.span("rw.core-call", rw_cap=rw_cap, max_k=max_k,
+                            max_rounds=max_rounds) as sp:
+            bits, over, rw_over = resilience.device_call(
+                "elle.rw-core-check",
+                lambda: _cc_call(h, n_keys, max_k, max_rounds, rw_cap),
+                policy=policy, deadline=deadline, plan=plan)
+            over_i = int(np.asarray(over))
+            rw_over_i = int(np.asarray(rw_over))
+            conv = int(np.asarray(bits)[-1]) == 1
+            retry = None
+            if rw_over_i > 0 and rw_cap < RW_CAP_LIMIT:
+                retry = "rw-cap"
+                need = min(rw_cap + rw_over_i, RW_CAP_LIMIT)
+                while rw_cap < need:
+                    rw_cap *= 2
+                rw_cap = min(rw_cap, RW_CAP_LIMIT)
+            elif over_i > 0 and max_k < MAX_K_CAP:
+                retry = "max-k"
+                need = max_k + over_i
+                while max_k < need:
+                    max_k *= 2
+                max_k = min(max_k, MAX_K_CAP)
+            elif not conv and over_i == 0 and max_rounds < MAX_ROUNDS_CAP:
+                retry = "rounds"
+                max_rounds = min(max_rounds * 2, MAX_ROUNDS_CAP)
+            # the budget this run's overflow regrows for the next run
+            sp.set_attr(retry=retry)
+        if retry is None:
+            break
 
     ph.end()
     row = np.asarray(bits)

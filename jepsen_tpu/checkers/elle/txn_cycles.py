@@ -13,6 +13,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from jepsen_tpu import telemetry
 from jepsen_tpu.checkers.elle.graph import (
     REL_NAMES,
     CycleSpec,
@@ -93,28 +94,46 @@ def _render_cycle(hit, explainer, n_txns, orig_index) -> List[dict]:
     return out
 
 
+def _padded_edges(src: np.ndarray, dst: np.ndarray):
+    """(src, dst, mask) padded to the next power of two with masked-off
+    edges at the end, so that histories of one size share their sweep
+    programs and witness ids still index the real edges."""
+    n = len(src)
+    cap = 1 << max(n - 1, 0).bit_length()
+    out_src = np.zeros(cap, np.int32)
+    out_dst = np.zeros(cap, np.int32)
+    out_src[:n], out_dst[:n] = src, dst
+    return out_src, out_dst, np.arange(cap) < n
+
+
 def _cycle_regions(proj: EdgeList, n_nodes: int, rank: np.ndarray,
                    use_device: bool):
     """Node regions containing cycles, or None if the projection is
     acyclic.  Device path: rank sweep -> witness backward edges -> local
-    BFS regions.  Host path: Tarjan SCCs."""
+    BFS regions.  Host path: Tarjan SCCs, exact, also where the device
+    path raises, does not converge or maps its witnesses to no region
+    (an `elle.host-fallback` span with the reason)."""
+    reason = None
     if use_device:
         try:
             import jax.numpy as jnp
 
             from jepsen_tpu.ops.cycle_sweep import SweepGraph, detect_cycles
 
+            src, dst, mask = _padded_edges(proj.src, proj.dst)
             g = SweepGraph(
                 n_nodes=n_nodes, rank=jnp.asarray(rank),
-                nc_src=jnp.asarray(proj.src), nc_dst=jnp.asarray(proj.dst),
-                nc_mask=jnp.ones(len(proj.src), bool),
+                nc_src=jnp.asarray(src), nc_dst=jnp.asarray(dst),
+                nc_mask=jnp.asarray(mask),
                 chain_nodes=jnp.zeros(0, jnp.int32),
                 chain_starts=jnp.zeros(0, bool),
                 chain_mask=jnp.zeros(0, bool))
             res = detect_cycles(g)
-            if res.converged:
-                if not res.has_cycle:
-                    return None
+            if not res.converged:
+                reason = "not-converged"
+            elif not res.has_cycle:
+                return None
+            else:
                 from jepsen_tpu.checkers.elle.list_append import (
                     _witness_regions,
                 )
@@ -122,7 +141,12 @@ def _cycle_regions(proj: EdgeList, n_nodes: int, rank: np.ndarray,
                     proj, proj.src, proj.dst, res.witness_edge_ids, n_nodes)
                 if regions:
                     return regions
-        except Exception:
-            pass  # fall through to exact host path
-    sccs = nontrivial_sccs(n_nodes, proj.src, proj.dst)
+                reason = "no-regions"
+        except Exception as e:  # noqa: BLE001 -- the host path is exact
+            reason = f"device-error:{type(e).__name__}"
+    if reason is None:
+        sccs = nontrivial_sccs(n_nodes, proj.src, proj.dst)
+    else:
+        with telemetry.span("elle.host-fallback", reason=reason):
+            sccs = nontrivial_sccs(n_nodes, proj.src, proj.dst)
     return sccs if sccs else None

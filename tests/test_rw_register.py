@@ -1,5 +1,6 @@
 """rw-register checker tests (reference rw_register_test.clj style)."""
 
+import numpy as np
 import pytest
 
 from jepsen_tpu.checkers.elle import rw_register
@@ -308,3 +309,165 @@ def test_fused_fast_path_on_large_history(monkeypatch):
     res_bad = rw.check(h, ["read-committed"])
     assert res_bad["valid?"] is False
     assert "G1c" in res_bad["anomalies"]
+
+
+# ---- spans of the fused check and the report path's sweep ----------------
+
+def _traced(fn):
+    from jepsen_tpu import telemetry
+
+    c = telemetry.activate(telemetry.Collector())
+    try:
+        return fn(), c
+    finally:
+        telemetry.deactivate(c)
+
+
+def _all_spans(c, name):
+    out, stack = [], list(c.roots)
+    while stack:
+        sp = stack.pop()
+        if sp.name == name:
+            out.append(sp)
+        stack += sp.children
+    return out
+
+
+def _blind_write_history(n):
+    """n readers of x = nil and n blind writers of x, all concurrent: the
+    rw join holds n * n edges, more than the fused check's first rw_cap
+    (the padded micro-op count) once n passes 16."""
+    return concurrent_history(
+        *[([["r", "x", None]], [["r", "x", None]]) for _ in range(n)],
+        *[([["w", "x", i]], [["w", "x", i]]) for i in range(n)])
+
+
+def test_fused_check_spans(monkeypatch):
+    monkeypatch.setattr(rw_register, "FUSED_MIN_TXNS", 0)
+    p = synth.packed_rw_history(n_txns=2000, n_keys=50, seed=4)
+    res, c = _traced(lambda: rw_register.check(p, ["snapshot-isolation"]))
+    assert res["valid?"] is True and res.get("fused-device") is True
+    (phase,) = _all_spans(c, "elle.rw-core-check")
+    assert [s.name for s in phase.children] == ["elle.pad", "rw.core-call"]
+    call = phase.children[1]
+    assert call.attrs["retry"] is None
+    assert call.attrs["rw_cap"] == 8192 and call.attrs["max_k"] == 128
+    assert phase.children[0].attrs["T"] == 2048
+
+
+def test_fused_check_regrows_rw_cap_in_a_second_call(monkeypatch):
+    monkeypatch.setattr(rw_register, "FUSED_MIN_TXNS", 0)
+    h = _blind_write_history(40)
+    res, c = _traced(lambda: rw_register.check(h, ["snapshot-isolation"]))
+    assert res["valid?"] is True and res.get("fused-device") is True
+    calls = _all_spans(c, "rw.core-call")
+    calls.sort(key=lambda s: s.t0)
+    assert [s.attrs["retry"] for s in calls] == ["rw-cap", None]
+    assert calls[0].attrs["rw_cap"] < 40 * 40 <= calls[1].attrs["rw_cap"]
+
+
+@pytest.mark.parametrize("make", [
+    lambda: synth.packed_rw_history(n_txns=2000, n_keys=50, seed=5),
+    lambda: _blind_write_history(40),
+    lambda: concurrent_history(
+        ([["r", "x", None], ["w", "y", 10]],
+         [["r", "x", None], ["w", "y", 10]]),
+        ([["r", "y", None], ["w", "x", 1]],
+         [["r", "y", None], ["w", "x", 1]]))],
+    ids=["valid", "rw-cap-regrow", "write-skew"])
+def test_fused_check_verdict_without_telemetry(monkeypatch, make):
+    monkeypatch.setattr(rw_register, "FUSED_MIN_TXNS", 0)
+    h = make()
+    on, _ = _traced(lambda: rw_register.check(h, ["serializable"]))
+    off = rw_register.check(h, ["serializable"])
+    assert on["valid?"] == off["valid?"]
+    assert on["anomaly-types"] == off["anomaly-types"]
+
+
+G1C = concurrent_history(
+    ([["w", "x", 1], ["r", "y", None]], [["w", "x", 1], ["r", "y", 9]]),
+    ([["w", "y", 9], ["r", "x", None]], [["w", "y", 9], ["r", "x", 1]]))
+
+
+def test_report_sweep_device_error_falls_back_on_the_record(monkeypatch):
+    from jepsen_tpu.ops import cycle_sweep
+
+    want = rw_register.check(G1C, ["read-committed"])
+
+    def broken(*a, **kw):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(cycle_sweep, "detect_cycles", broken)
+    got, c = _traced(lambda: rw_register.check(G1C, ["read-committed"]))
+    assert got["valid?"] is want["valid?"] is False
+    assert got["anomaly-types"] == want["anomaly-types"]
+    falls = _all_spans(c, "elle.host-fallback")
+    assert falls and {s.attrs["reason"] for s in falls} == {
+        "device-error:RuntimeError"}
+
+
+@pytest.mark.parametrize("converged,reason", [(False, "not-converged"),
+                                              (True, "no-regions")])
+def test_report_sweep_fallback_reasons(monkeypatch, converged, reason):
+    import dataclasses
+
+    from jepsen_tpu.checkers.elle import list_append
+    from jepsen_tpu.ops import cycle_sweep
+
+    want = rw_register.check(G1C, ["read-committed"])
+    orig = cycle_sweep.detect_cycles
+    monkeypatch.setattr(
+        cycle_sweep, "detect_cycles",
+        lambda *a, **kw: dataclasses.replace(orig(*a, **kw),
+                                             converged=converged))
+    monkeypatch.setattr(list_append, "_witness_regions",
+                        lambda *a, **kw: [])
+    got, c = _traced(lambda: rw_register.check(G1C, ["read-committed"]))
+    assert got["anomaly-types"] == want["anomaly-types"]
+    assert {s.attrs["reason"] for s in _all_spans(
+        c, "elle.host-fallback")} == {reason}
+
+
+def test_report_sweep_has_no_fallback_span_on_the_device_path():
+    res, c = _traced(lambda: rw_register.check(G1C, ["read-committed"]))
+    assert "G1c" in res["anomaly-types"]
+    assert not _all_spans(c, "elle.host-fallback")
+
+
+def _chain_with_cycle(n_edges):
+    """A graph of n_edges edges on 64 nodes: a forward chain and one
+    backward edge closing a cycle."""
+    from jepsen_tpu.checkers.elle.graph import EdgeList
+
+    src = np.arange(n_edges - 1) % 63
+    e = EdgeList()
+    e.src = np.concatenate([src, [5]]).astype(np.int32)
+    e.dst = np.concatenate([src + 1, [2]]).astype(np.int32)
+    e.rel = np.zeros(n_edges, np.int8)
+    return e
+
+
+def test_report_sweep_shares_one_program_across_edge_counts(monkeypatch):
+    """Two graphs whose edge counts differ within one power of two give
+    the host's verdict and run one sweep program between them."""
+    from jepsen_tpu import compilecache
+    from jepsen_tpu.checkers.elle import txn_cycles
+
+    shapes = []
+    real = compilecache.call
+
+    def spy(name, fn, *args, **kw):
+        if name == "cycle-sweep":
+            shapes.append(tuple(a.shape for a in args))
+        return real(name, fn, *args, **kw)
+
+    monkeypatch.setattr(compilecache, "call", spy)
+    rank = np.arange(64, dtype=np.int32)
+    for n in (70, 90):
+        proj = _chain_with_cycle(n)
+        dev = txn_cycles._cycle_regions(proj, 64, rank, use_device=True)
+        host = txn_cycles._cycle_regions(proj, 64, rank, use_device=False)
+        assert dev is not None and host is not None
+        assert set(np.concatenate(dev)) <= set(np.concatenate(host))
+    assert len(shapes) == 2 and len(set(shapes)) == 1
+    assert shapes[0][1] == (128,)
