@@ -503,11 +503,23 @@ class FamilyGraph:
     chain_masks: Tuple[jnp.ndarray, ...]  # per group (C_g,) bool
     #: (back_all, count_f, fam_src, fam_dst) of `enumerate_families`
     enumeration: Optional[tuple] = None
+    #: host copy of the union's backward-edge positions, made on the
+    #: first witness map that needs it (`dataclasses.replace` starts a
+    #: new graph without it)
+    _host_back: Optional[np.ndarray] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
     @property
     def k_tab(self) -> int:
         """Edges a family in the endpoint tables (0: not enumerated)."""
         return self.enumeration[2].shape[1] if self.enumeration else 0
+
+    def host_backward(self) -> np.ndarray:
+        """Positions of the union's backward edges, in edge order: one
+        device-to-host copy of the enumeration's `back_all` per graph."""
+        if self._host_back is None:
+            self._host_back = np.nonzero(np.asarray(self.enumeration[0]))[0]
+        return self._host_back
 
     def project(self, inc: Sequence[int],
                 cinc: Sequence[int]) -> "FamilyProjection":
@@ -525,14 +537,17 @@ class FamilyProjection:
     inc: Tuple[int, ...]
     cinc: Tuple[int, ...]
 
-    def host_mask(self) -> np.ndarray:
-        """The projection's edge mask on the host (for the witness map)."""
-        mask = np.array(self.graph.base_mask)
+    def host_backward(self) -> np.ndarray:
+        """The projection's backward-edge positions on the host, in edge
+        order: the union's (`FamilyGraph.host_backward`) in the blocks
+        of the families it keeps, since a kept family's backward set is
+        the union's there."""
+        pos = self.graph.host_backward()
         bounds = np.cumsum((0,) + tuple(self.graph.fam_lens))
-        for lo, hi, keep in zip(bounds[:-1], bounds[1:], self.inc):
-            if not keep:
-                mask[lo:hi] = False
-        return mask
+        cut = np.searchsorted(pos, bounds)
+        return np.concatenate([pos[:0]] + [
+            pos[lo:hi] for lo, hi, keep in zip(cut[:-1], cut[1:], self.inc)
+            if keep])
 
 
 @partial(jax.jit, static_argnames=("n_nodes", "k_tab", "fam_lens", "mesh"))
@@ -703,6 +718,7 @@ def detect_cycles(g: SweepGraph | FamilyProjection, max_k: int = 128,
     with telemetry.span("sweep.call") as sp:
         has, wit, n_back, conv = _run_sweep(g, max_k, max_rounds, mesh, axis)
         n_back = int(n_back)
+        has = bool(has)
         fits = n_back <= max_k
         if fits:
             conv = bool(conv)
@@ -719,7 +735,7 @@ def detect_cycles(g: SweepGraph | FamilyProjection, max_k: int = 128,
             # still cannot fit it would be a guaranteed-wasted sweep):
             # report inexact — the caller falls back to the host oracle,
             # same contract as grow_until_exact
-            return SweepResult(has_cycle=bool(has),
+            return SweepResult(has_cycle=has,
                                witness_edge_ids=np.zeros(0, np.int64),
                                n_backward=n_back, converged=False)
         # too many backward edges for the bit budget: double and retry
@@ -737,22 +753,29 @@ def detect_cycles(g: SweepGraph | FamilyProjection, max_k: int = 128,
                                             MAX_ROUNDS_CAP),
                              deadline=deadline, mesh=mesh, axis=axis)
     with telemetry.span("sweep.witness-map") as sp:
-        wit = np.asarray(wit)
-        has = bool(has)
-        # map witness backward-edge ids back to edge-array positions
-        if isinstance(g, FamilyProjection):
-            mask, base = g.host_mask(), g.graph
-        else:
-            mask, base = np.asarray(g.nc_mask), g
-        rank = np.asarray(base.rank)
-        src = np.clip(np.asarray(base.nc_src), 0, base.n_nodes - 1)
-        dst = np.clip(np.asarray(base.nc_dst), 0, base.n_nodes - 1)
-        is_back = mask & (rank[src] >= rank[dst])
-        back_pos = np.nonzero(is_back)[0]
-        wit_ids = back_pos[np.nonzero(wit[:len(back_pos)])[0]] \
-            if len(back_pos) else np.zeros(0, np.int64)
+        # the witness bits are diagonal(closure) & bvalid and has_cycle is
+        # their any(): without a cycle there is no bit to map, so nothing
+        # is copied from the device
+        base = g.graph if isinstance(g, FamilyProjection) else g
+        wit_ids = np.zeros(0, np.int64)
+        host_copy = False
+        if has:
+            # map witness backward-edge ids back to edge-array positions
+            if isinstance(g, FamilyProjection):
+                host_copy = base._host_back is None
+                back_pos = g.host_backward()
+            else:
+                rank = np.asarray(g.rank)
+                src = np.clip(np.asarray(g.nc_src), 0, g.n_nodes - 1)
+                dst = np.clip(np.asarray(g.nc_dst), 0, g.n_nodes - 1)
+                back_pos = np.nonzero(np.asarray(g.nc_mask)
+                                      & (rank[src] >= rank[dst]))[0]
+            wit = np.asarray(wit)
+            wit_ids = back_pos[np.nonzero(wit[:len(back_pos)])[0]]
         if telemetry.enabled():
-            sp.set_attr(edges=len(mask), witnesses=len(wit_ids))
+            sp.set_attr(edges=int(base.nc_src.shape[0]),
+                        witnesses=len(wit_ids), mapped=has,
+                        host_copy=host_copy)
     return SweepResult(has_cycle=has, witness_edge_ids=wit_ids,
                        n_backward=n_back, converged=conv)
 

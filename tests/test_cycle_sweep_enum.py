@@ -8,7 +8,10 @@ Also: `projection_scan`'s outputs on one fixed graph, as they were
 before it shared `enumerate_families`; the union tables against a numpy
 enumeration; no edge-sized scatter or rank gather in the per-projection
 program outside its `n_back > 0` cond; one `sweep.enumerate` span per
-list-append check.
+list-append check.  The witness map against a numpy map of the sweep
+program's own witness bits, and its spans: a map (and one host copy of
+the union's enumeration per check) only after a sweep that found a
+cycle.
 """
 
 import jax
@@ -336,3 +339,74 @@ def test_one_enumerate_span_per_check(cycle):
     # every projection's backward set lies in the union's
     assert all(x.attrs["n_backward"] <= n_union for x in calls)
     assert n_union >= cycle
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("case", ["backward-acyclic", "backward-cycle",
+                                  "past-max-k"])
+def test_witness_ids_are_the_numpy_map_of_the_sweep_bits(case, chips):
+    """`detect_cycles`' witness ids on every projection are the edge
+    positions of `mask & rank[src] >= rank[dst]` at the witness bits the
+    sweep program itself returns (mapped only after a cycle, from the
+    union's host copy)."""
+    seed, n_back, cycle = CASES[case]
+    gr = _graph(seed, n_back, cycle)
+    mesh = _mesh(chips)
+    fam = cs.enumerate_backward(_family_graph(gr), mesh=mesh)
+    wide = cs.enumerate_backward(_family_graph(gr), k_tab=512, mesh=mesh)
+    n_cyclic = 0
+    for inc, cinc in PROJECTIONS:
+        got = cs.detect_cycles(fam.project(inc, cinc), mesh=mesh)
+        back = _numpy_back(gr, inc)
+        # the budget `detect_cycles` grows to: 128, else the next pow2
+        max_k = 128 if back.sum() <= 128 else cs._pow2(int(back.sum()))
+        has, wit, nb, conv = cs._run_sweep(wide.project(inc, cinc), max_k,
+                                           64, mesh, "batch")
+        assert (int(nb), bool(conv)) == (int(back.sum()), True)
+        pos = np.nonzero(back)[0]
+        want = pos[np.nonzero(np.asarray(wit)[:len(pos)])[0]]
+        assert np.array_equal(got.witness_edge_ids, want)
+        assert got.has_cycle is bool(has) is (len(want) > 0)
+        n_cyclic += got.has_cycle
+    assert (n_cyclic > 0) is cycle
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("cycle", [False, True])
+def test_witness_map_only_after_a_cycle(cycle, chips, monkeypatch):
+    from jepsen_tpu import telemetry
+    from jepsen_tpu.checkers.elle import list_append
+    from jepsen_tpu.workloads import synth
+
+    monkeypatch.setenv("JEPSEN_SHARDS", str(chips))
+    h = synth.la_history(n_txns=120, n_keys=5, concurrency=4, seed=11)
+    if cycle:
+        assert synth.inject_wr_cycle(h)
+    c = telemetry.activate()
+    try:
+        for _ in range(2):
+            with telemetry.span("check"):
+                res = list_append.check(h, ["strict-serializable"])
+            assert res["valid?"] is (not cycle)
+    finally:
+        telemetry.deactivate(c)
+    assert len(c.roots) == 2
+    for root in c.roots:
+        sweep = next(x for x in root.children
+                     if x.name == "elle.cycle-sweep")
+        calls = [x for x in sweep.children if x.name == "sweep.call"]
+        maps = [x for x in sweep.children if x.name == "sweep.witness-map"]
+        # still one map span per sweep
+        assert len(maps) == len(calls) == sweep.attrs["projections"]
+        assert all(x.attrs["sharded"] is (chips > 1) for x in calls)
+        mapped = [m for m in maps if m.attrs["mapped"]]
+        copies = [m for m in maps if m.attrs["host_copy"]]
+        assert all(m.attrs["witnesses"] == 0 for m in maps
+                   if not m.attrs["mapped"])
+        if cycle:
+            assert mapped and all(m.attrs["witnesses"] >= 1
+                                  for m in mapped)
+            # the first map builds the host copy; the rest reuse it
+            assert copies == mapped[:1]
+        else:
+            assert mapped == [] and copies == []
