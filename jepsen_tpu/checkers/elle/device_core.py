@@ -29,9 +29,14 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from jepsen_tpu.checkers.elle.device_infer import PaddedLA, infer
-# budget caps live with the sweep kernel; re-exported for callers
-from jepsen_tpu.ops.cycle_sweep import (  # noqa: F401
+from jepsen_tpu.checkers.elle.device_infer import (
+    PaddedLA,
+    family_graph,
+    includes,
+    infer,
+)
+from jepsen_tpu.checkers.elle.graph import REL_CODES
+from jepsen_tpu.ops.cycle_sweep import (
     MAX_K_CAP,
     MAX_ROUNDS_CAP,
     projection_scan,
@@ -62,52 +67,27 @@ def _cc(site, jitfn, *args, **static):
 
 
 def proj_include_stack(projections=PROJECTIONS) -> jnp.ndarray:
-    """(P, 5) family-include flags for the ww/wr/rw/tb/bt edge families
-    (tb/bt are the realtime-barrier families)."""
-    return jnp.asarray([
-        [int("ww" in p), int("wr" in p), int("rw" in p),
-         int("realtime" in p), int("realtime" in p)]
-        for p in projections], jnp.int32)
+    """(P, F) family-include flags of `device_infer.family_graph`."""
+    return jnp.asarray([includes({REL_CODES[n] for n in p})[0]
+                        for p in projections], jnp.int32)
 
 
 def chain_include_stack(projections=PROJECTIONS) -> jnp.ndarray:
-    """(P, 2) chain-group include flags for [process, barrier] chains."""
-    return jnp.asarray([
-        [int("process" in p), int("realtime" in p)]
-        for p in projections], jnp.int32)
+    """(P, G) chain-group include flags of `device_infer.family_graph`."""
+    return jnp.asarray([includes({REL_CODES[n] for n in p})[1]
+                        for p in projections], jnp.int32)
 
 
-def _verdict(out, max_k: int, max_rounds: int):
+def _verdict(out, max_k: int, max_rounds: int, axis=None, n_shards: int = 1):
     """Sweep half of the core check: infer output -> (bits, overflowed).
     Plain function — jitted fused with infer by `core_check`, or as its
-    own (much smaller) XLA program by `core_check_staged`."""
-    T = out["ranks"]["txn"].shape[0]
-    edges = out["edges"]
-    chains = out["chains"]
-    rank = jnp.concatenate([out["ranks"]["txn"], out["ranks"]["barrier"]])
-    e_src = jnp.concatenate([edges[k][0] for k in ("ww", "wr", "rw", "tb",
-                                                   "bt")])
-    e_dst = jnp.concatenate([edges[k][1] for k in ("ww", "wr", "rw", "tb",
-                                                   "bt")])
-    masks = {k: edges[k][2] for k in ("ww", "wr", "rw", "tb", "bt")}
-
-    pc_nodes, pc_starts, pc_mask = chains["process"]
-    bc_nodes, bc_starts, bc_mask = chains["barrier"]
-    chain_nodes = jnp.concatenate([pc_nodes, bc_nodes])
-    chain_starts = jnp.concatenate([pc_starts, bc_starts])
-
-    # One sweep instantiation scanned over the 5 projections (a Python loop
-    # would inline 5 copies of the while_loop kernel and quintuple XLA
-    # compile time — measured 125.8 s at 100k-txn shapes in round 2).  The
-    # scan keeps exactly one (N, max_k) label plane live (bounds HBM at
-    # 10M ops) and consumes family-include flags instead of (5, E) mask
-    # stacks — see projection_scan / PROFILE.md §0b for the hoist.
+    own (much smaller) XLA program by `core_check_staged`; with `axis`
+    (inside a shard_map over a mesh axis of `n_shards` devices) each
+    device sweeps its window of the backward-edge axis."""
     conv_all, overflow, cyc_bits = projection_scan(
-        2 * T, max_k, max_rounds, rank, e_src, e_dst,
-        [masks[k] for k in ("ww", "wr", "rw", "tb", "bt")],
-        proj_include_stack(PROJECTIONS),
-        chain_nodes, chain_starts, [pc_mask, bc_mask],
-        chain_include_stack(PROJECTIONS))
+        family_graph(out), max_k, max_rounds,
+        proj_include_stack(PROJECTIONS), chain_include_stack(PROJECTIONS),
+        axis=axis, n_shards=n_shards)
 
     counts = jnp.stack([out["counts"][n].astype(jnp.int32)
                         for n in COUNT_NAMES])

@@ -38,6 +38,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jepsen_tpu.checkers.elle.graph import (
+    REL_PROCESS,
+    REL_REALTIME,
+    REL_RW,
+    REL_WR,
+    REL_WW,
+)
 from jepsen_tpu.history.soa import (
     MOP_APPEND,
     MOP_READ,
@@ -47,6 +54,7 @@ from jepsen_tpu.history.soa import (
     PackedTxns,
 )
 from jepsen_tpu.ops import pallas_fill
+from jepsen_tpu.ops.cycle_sweep import FamilyGraph
 from jepsen_tpu.ops.segments import (
     segment_ids_from_starts,
     segmented_cummax,
@@ -55,6 +63,15 @@ from jepsen_tpu.ops.segments import (
 
 BIG = jnp.int32(2 ** 30)
 BIG_I = 2 ** 30  # host-side twin (the IR column derivation)
+
+#: the inferred edge families, in the order `family_graph` concatenates
+#: them, and the rel of each (tb/bt: the realtime edges into and out of
+#: the barrier nodes)
+FAMILIES = ("ww", "wr", "rw", "tb", "bt")
+FAMILY_RELS = (REL_WW, REL_WR, REL_RW, REL_REALTIME, REL_REALTIME)
+#: the chain groups, in `family_graph`'s order, and the rel of each
+CHAINS = ("process", "barrier")
+CHAIN_RELS = (REL_PROCESS, REL_REALTIME)
 
 
 @dataclasses.dataclass
@@ -893,3 +910,27 @@ def infer(h: PaddedLA, n_keys: int) -> Dict[str, dict]:
             "writer": writer,
         },
     }
+
+
+def family_graph(out) -> FamilyGraph:
+    """The cycle sweep's graph of an inference's `edges`, `chains` and
+    `ranks` (`infer`, `device_rw.infer_rw`), not yet enumerated: txn
+    nodes 0..T-1, barrier nodes T..2T-1."""
+    edges, chains = out["edges"], out["chains"]
+    return FamilyGraph(
+        n_nodes=2 * out["ranks"]["txn"].shape[0],
+        rank=jnp.concatenate([out["ranks"]["txn"], out["ranks"]["barrier"]]),
+        nc_src=jnp.concatenate([edges[k][0] for k in FAMILIES]),
+        nc_dst=jnp.concatenate([edges[k][1] for k in FAMILIES]),
+        base_mask=jnp.concatenate([edges[k][2] for k in FAMILIES]),
+        fam_lens=tuple(edges[k][0].shape[0] for k in FAMILIES),
+        chain_nodes=jnp.concatenate([chains[c][0] for c in CHAINS]),
+        chain_starts=jnp.concatenate([chains[c][1] for c in CHAINS]),
+        chain_masks=tuple(chains[c][2] for c in CHAINS))
+
+
+def includes(rels):
+    """(family flags, chain-group flags), 1 or 0 each: what the
+    projection of a `family_graph` onto the rel codes `rels` keeps."""
+    return ([int(r in rels) for r in FAMILY_RELS],
+            [int(r in rels) for r in CHAIN_RELS])

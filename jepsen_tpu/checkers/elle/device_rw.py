@@ -42,7 +42,11 @@ from jepsen_tpu.checkers.elle.device_core import (
     chain_include_stack,
     proj_include_stack,
 )
-from jepsen_tpu.checkers.elle.device_infer import PaddedLA, pad_packed
+from jepsen_tpu.checkers.elle.device_infer import (
+    PaddedLA,
+    family_graph,
+    pad_packed,
+)
 from jepsen_tpu.history.soa import (
     MOP_APPEND,
     MOP_READ,
@@ -51,7 +55,12 @@ from jepsen_tpu.history.soa import (
     TXN_OK,
     PackedTxns,
 )
-from jepsen_tpu.ops.cycle_sweep import _sweep_arrays, projection_scan
+from jepsen_tpu.ops.cycle_sweep import (
+    MAX_K_CAP,
+    MAX_ROUNDS_CAP,
+    FamilyGraph,
+    projection_scan,
+)
 from jepsen_tpu.ops.segments import segmented_cummax, segmented_cumsum
 
 BIG = jnp.int32(2 ** 30)
@@ -313,45 +322,24 @@ def rw_core_check(h: PaddedLA, n_keys: int, max_k: int = 128,
     sweeps (grow and retry); rw_overflow: rw-join edges beyond rw_cap
     (grow rw_cap or fall back to the host checker)."""
     out = infer_rw(h, n_keys, rw_cap=rw_cap)
-    T = h.txn_type.shape[0]
-    edges = out["edges"]
-    chains = out["chains"]
-    rank = jnp.concatenate([out["ranks"]["txn"], out["ranks"]["barrier"]])
-    e_src = jnp.concatenate([edges[k][0] for k in ("ww", "wr", "rw", "tb",
-                                                   "bt")])
-    e_dst = jnp.concatenate([edges[k][1] for k in ("ww", "wr", "rw", "tb",
-                                                   "bt")])
-    masks = {k: edges[k][2] for k in ("ww", "wr", "rw", "tb", "bt")}
-
-    pc_nodes, pc_starts, pc_mask = chains["process"]
-    bc_nodes, bc_starts, bc_mask = chains["barrier"]
-    chain_nodes = jnp.concatenate([pc_nodes, bc_nodes])
-    chain_starts = jnp.concatenate([pc_starts, bc_starts])
-
-    # one sweep instantiation scanned over the 5 projections via the
-    # shared hoisted form (family-include flags + one shared backward
-    # enumeration; see cycle_sweep.projection_scan / PROFILE.md §0b)
     conv_all, overflow, cyc_bits = projection_scan(
-        2 * T, max_k, max_rounds, rank, e_src, e_dst,
-        [masks[k] for k in ("ww", "wr", "rw", "tb", "bt")],
-        proj_include_stack(PROJECTIONS),
-        chain_nodes, chain_starts, [pc_mask, bc_mask],
-        chain_include_stack(PROJECTIONS))
+        family_graph(out), max_k, max_rounds,
+        proj_include_stack(PROJECTIONS), chain_include_stack(PROJECTIONS))
 
-    # cyclic versions: rank sweep over the version graph (no chains)
+    # cyclic versions: the version graph's one projection, all of it
     ver = out["versions"]
     vn_nodes = h.rd_elems.shape[0] + max(n_keys, 1)  # static: V + nk
-    vempty_i = jnp.zeros(0, jnp.int32)
-    vempty_b = jnp.zeros(0, bool)
-    v_has, _, v_back, v_conv = _sweep_arrays(
-        vn_nodes, max_k, max_rounds, ver["rank"],
-        ver["src"], ver["dst"], ver["mask"], vempty_i, vempty_b, vempty_b)
+    whole = jnp.ones((1, 1), jnp.int32)
+    v_conv, v_over, v_bits = projection_scan(
+        FamilyGraph.plain(vn_nodes, ver["rank"], ver["src"], ver["dst"],
+                          ver["mask"]),
+        max_k, max_rounds, whole, whole)
     conv_all = conv_all & v_conv
-    overflow = jnp.maximum(overflow, jnp.maximum(v_back - max_k, 0))
+    overflow = jnp.maximum(overflow, v_over)
 
     counts = jnp.stack(
         [out["counts"][n].astype(jnp.int32) for n in COUNT_NAMES_RW[:-1]]
-        + [v_has.astype(jnp.int32)])
+        + [v_bits[0]])
     bits = jnp.concatenate(
         [counts, cyc_bits, conv_all.astype(jnp.int32)[None]])
     return bits, overflow, out["rw_overflow"]
@@ -373,11 +361,6 @@ def check(p: PackedTxns | PaddedLA, n_keys: int = None, max_k: int = 128,
     `deadline` is polled before each grow-retry and raises
     `DeadlineExceeded` on expiry — `rw_register.check` and `check_safe`
     map that to an unknown/degraded verdict."""
-    from jepsen_tpu.checkers.elle.device_core import (
-        MAX_K_CAP,
-        MAX_ROUNDS_CAP,
-    )
-
     from jepsen_tpu import resilience, telemetry
 
     # one phase span over the whole device check, the host pad and every

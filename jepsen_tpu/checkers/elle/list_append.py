@@ -24,21 +24,22 @@ are never approximated.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Sequence, Set, Tuple
+from typing import Any, Dict, List, Sequence, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
 from jepsen_tpu import resilience, telemetry
 from jepsen_tpu.checkers.elle import consistency, coverage, oracle
-from jepsen_tpu.checkers.elle.device_infer import PaddedLA, infer, pad_packed
+from jepsen_tpu.checkers.elle.device_infer import (
+    CHAIN_RELS,
+    CHAINS,
+    FAMILY_RELS,
+    family_graph,
+    includes,
+    infer,
+    pad_packed,
+)
 from jepsen_tpu.checkers.elle.graph import (
-    REL_NAMES,
-    REL_PROCESS,
-    REL_REALTIME,
-    REL_RW,
-    REL_WR,
-    REL_WW,
     CycleSpec,
     EdgeList,
     find_cycle,
@@ -48,16 +49,9 @@ from jepsen_tpu.history.ir import HistoryIR
 from jepsen_tpu.history.soa import TXN_OK, PackedTxns, pack_txns
 from jepsen_tpu.ops.cycle_sweep import (
     MAX_K_CAP,
-    FamilyGraph,
     detect_cycles,
     enumerate_backward,
 )
-
-#: the inferred edge families, in the order the sweep concatenates them,
-#: and the rel of each (tb/bt: the realtime edges into and out of the
-#: barrier nodes)
-FAMILIES = ("ww", "wr", "rw", "tb", "bt")
-FAMILY_RELS = (REL_WW, REL_WR, REL_RW, REL_REALTIME, REL_REALTIME)
 
 
 def check(history, consistency_models: Sequence[str] = ("serializable",),
@@ -194,26 +188,8 @@ def _check_device(history, consistency_models, anomalies, max_reported,
         projections.setdefault(spec.rels, []).append((name, spec))
 
     T = h.txn_type.shape[0]
-    edges = out["edges"]
-    chains = out["chains"]
-    rank = jnp.concatenate([out["ranks"]["txn"], out["ranks"]["barrier"]])
-
-    # static concatenated edge families; each projection keeps whole
-    # families (by rel) and chain groups
-    e_src = jnp.concatenate([edges[k][0] for k in FAMILIES])
-    e_dst = jnp.concatenate([edges[k][1] for k in FAMILIES])
-    sizes = tuple(edges[k][0].shape[0] for k in FAMILIES)
-    rel_of = np.repeat(np.asarray(FAMILY_RELS, np.int8), sizes)
-    base_mask = jnp.concatenate([edges[k][2] for k in FAMILIES])
-
-    pc_nodes, pc_starts, pc_mask = chains["process"]
-    bc_nodes, bc_starts, bc_mask = chains["barrier"]
-    fam = FamilyGraph(
-        n_nodes=2 * T, rank=rank, nc_src=e_src, nc_dst=e_dst,
-        base_mask=base_mask, fam_lens=sizes,
-        chain_nodes=jnp.concatenate([pc_nodes, bc_nodes]),
-        chain_starts=jnp.concatenate([pc_starts, bc_starts]),
-        chain_masks=(pc_mask, bc_mask))
+    # each projection keeps whole edge families (by rel) and chain groups
+    fam = family_graph(out)
 
     host_edges: EdgeList = None  # lazily materialized for classification
     explainer = None             # lazily built per-edge Explainer
@@ -239,8 +215,7 @@ def _check_device(history, consistency_models, anomalies, max_reported,
                     **{"anomaly-types": sorted(found),
                        "anomalies": found, "not": [], "also-not": [],
                        "partial": "cycle-sweep interrupted"})
-        g = fam.project([int(r in rels) for r in FAMILY_RELS],
-                        [int(REL_PROCESS in rels), int(REL_REALTIME in rels)])
+        g = fam.project(*includes(rels))
         res = dev("elle.cycle-sweep",
                   lambda g=g: detect_cycles(g, deadline=deadline,
                                             mesh=mesh))
@@ -252,17 +227,16 @@ def _check_device(history, consistency_models, anomalies, max_reported,
         # ---- host classification over witness regions --------------------
         with telemetry.span("elle.classify") as sp:
             if host_edges is None:
-                host_edges = _materialize_host_edges(
-                    e_src, e_dst, base_mask, rel_of, chains, T)
-            proj = host_edges.project(_expand_rels(rels))
+                host_edges = _materialize_host_edges(fam, out["chains"])
+            proj = host_edges.project(rels)
             regions = _witness_regions(
-                proj, np.asarray(e_src), np.asarray(e_dst),
+                proj, np.asarray(fam.nc_src), np.asarray(fam.nc_dst),
                 res.witness_edge_ids, 2 * T, limit=16)
             n_found = 0
             for name, spec in group:
                 hit = None
                 for region in regions:
-                    hit = find_cycle(region, proj, _spec_with_chains(spec))
+                    hit = find_cycle(region, proj, spec)
                     if hit is not None:
                         break
                 if hit is not None:
@@ -322,25 +296,17 @@ def _check_device(history, consistency_models, anomalies, max_reported,
     return verdict
 
 
-def _expand_rels(rels: frozenset) -> Set[int]:
-    """Projection rel set for host classification (chains share rel codes)."""
-    return set(rels)
-
-
-def _spec_with_chains(spec: CycleSpec) -> CycleSpec:
-    return spec
-
-
-def _materialize_host_edges(e_src, e_dst, mask, rel_of, chains, T
-                            ) -> EdgeList:
-    """Pull device edges + chain-implied edges into a host EdgeList."""
-    src = np.asarray(e_src)
-    dst = np.asarray(e_dst)
-    m = np.asarray(mask)
+def _materialize_host_edges(fam, chains) -> EdgeList:
+    """Pull a `family_graph`'s edges + chain-implied edges into a host
+    EdgeList."""
+    src = np.asarray(fam.nc_src)
+    dst = np.asarray(fam.nc_dst)
+    m = np.asarray(fam.base_mask)
+    rel_of = np.repeat(np.asarray(FAMILY_RELS, np.int8), fam.fam_lens)
     parts_s = [src[m]]
     parts_d = [dst[m]]
     parts_r = [rel_of[m]]
-    for cname, rel in (("process", REL_PROCESS), ("barrier", REL_REALTIME)):
+    for cname, rel in zip(CHAINS, CHAIN_RELS):
         nodes, starts, cm = (np.asarray(x) for x in chains[cname])
         ok = cm[:-1] & cm[1:] & ~starts[1:]
         parts_s.append(nodes[:-1][ok])

@@ -116,19 +116,10 @@ def _cycle_regions(proj: EdgeList, n_nodes: int, rank: np.ndarray,
     reason = None
     if use_device:
         try:
-            import jax.numpy as jnp
+            from jepsen_tpu.ops.cycle_sweep import FamilyGraph, detect_cycles
 
-            from jepsen_tpu.ops.cycle_sweep import SweepGraph, detect_cycles
-
-            src, dst, mask = _padded_edges(proj.src, proj.dst)
-            g = SweepGraph(
-                n_nodes=n_nodes, rank=jnp.asarray(rank),
-                nc_src=jnp.asarray(src), nc_dst=jnp.asarray(dst),
-                nc_mask=jnp.asarray(mask),
-                chain_nodes=jnp.zeros(0, jnp.int32),
-                chain_starts=jnp.zeros(0, bool),
-                chain_mask=jnp.zeros(0, bool))
-            res = detect_cycles(g)
+            res = detect_cycles(FamilyGraph.plain(
+                n_nodes, rank, *_padded_edges(proj.src, proj.dst)))
             if not res.converged:
                 reason = "not-converged"
             elif not res.has_cycle:

@@ -52,32 +52,9 @@ from jepsen_tpu.ops.segments import (
 )
 
 
-@dataclasses.dataclass
-class SweepGraph:
-    """Static, padded graph layout for the sweep kernel (device arrays).
-
-    Non-chain edges are COO (src, dst, mask).  Chain edges are given as
-    concatenated node sequences: chain_nodes with chain_starts flags; the
-    implied edges are chain_nodes[i] -> chain_nodes[i+1] within a segment.
-    chain_mask disables whole entries (padding / rel not in projection).
-    All ranks must be unique per node; forward = rank increases.
-    """
-
-    n_nodes: int
-    rank: jnp.ndarray          # (N,) int32, unique
-    nc_src: jnp.ndarray        # (E,) int32 non-chain edges
-    nc_dst: jnp.ndarray        # (E,) int32
-    nc_mask: jnp.ndarray       # (E,) bool
-    chain_nodes: jnp.ndarray   # (C,) int32
-    chain_starts: jnp.ndarray  # (C,) bool
-    chain_mask: jnp.ndarray    # (C,) bool
-
-
 def backward_test(rank, nc_src, nc_dst, n_nodes: int):
-    """The projection-independent backward-edge test (edge goes backward
-    iff rank does not increase).  Single source of truth for callers that
-    hoist it out of a projection scan AND for `_sweep_window`'s internal
-    fallback — the two must stay bit-identical."""
+    """`enumerate_families`' rank test: an edge goes backward iff rank
+    does not increase along it."""
     return rank[jnp.clip(nc_src, 0, n_nodes - 1)] >= \
         rank[jnp.clip(nc_dst, 0, n_nodes - 1)]
 
@@ -85,7 +62,7 @@ def backward_test(rank, nc_src, nc_dst, n_nodes: int):
 def _sweep_window(n_nodes: int, k_total: int, k_local: int, max_rounds: int,
                   rank, nc_src, nc_dst, nc_mask,
                   chain_nodes, chain_starts, chain_mask,
-                  k_offset, axis_name=None, back_pre=None, back_tables=None):
+                  k_offset, back_pre, back_tables, axis_name=None):
     """Sweep kernel over a window of the backward-edge axis.
 
     Each caller owns backward edges with global ids in
@@ -98,47 +75,18 @@ def _sweep_window(n_nodes: int, k_total: int, k_local: int, max_rounds: int,
     full (k_total, k_total) meta graph and computes the closure redundantly
     (it is k_total^2 bytes — trivial next to the label planes).
 
+    `back_pre` (is_back, n_back) and `back_tables` (the (k_total,)
+    bsrc, bdst endpoint tables) are one projection's backward-edge
+    enumeration, read by `project_families` off its union's
+    (`enumerate_families`); chain edges are forward by construction
+    (callers guarantee ranks increase along chains).
+
     Returns (has_cycle, witness_bits (k_total,), n_backward, converged) —
     replicated across the axis when axis_name is set.
     """
-    # ---- split edges: backward iff rank[src] >= rank[dst] -----------------
-    # (chain edges are forward by construction: caller guarantees ranks
-    # increase along chains)
-    if back_pre is not None:
-        # caller hoisted the backward enumeration: (is_back, n_back), and
-        # the (k_total,) endpoint tables `back_tables` that come with it.
-        # `project_families` reads both off ONE enumeration of the family
-        # union, where each projection ran a rank test, an E-sized cumsum
-        # and the two E-sized scatter-max reductions below (on TPU the
-        # scatters measured 2.4 s/run at 1M shapes: 0.24 s x 2 x 5
-        # projections, ~24% of the whole check).  Must be bit-identical
-        # to the block below.
-        is_back, n_back = back_pre
-        bsrc_full, bdst_full = back_tables
-        bdst_local = jax.lax.dynamic_slice(
-            bdst_full, (k_offset,), (k_local,))
-    else:
-        is_back = nc_mask & backward_test(rank, nc_src, nc_dst, n_nodes)
-        n_back = jnp.sum(is_back.astype(jnp.int32))
-
-        # stable enumeration of backward edges: order by edge position
-        back_order = jnp.cumsum(is_back.astype(jnp.int32)) - 1
-        back_id = jnp.where(is_back, back_order, -1)
-
-        # full-width source table (identical on every window — needed
-        # for the meta-graph columns)
-        in_full = is_back & (back_id < k_total)
-        scat_full = jnp.where(in_full, back_id, k_total).astype(jnp.int32)
-        bsrc_full = jnp.zeros((k_total + 1,), jnp.int32).at[scat_full].max(
-            jnp.where(in_full, nc_src, 0))[:k_total]
-
-        # local window endpoints
-        in_local = is_back & (back_id >= k_offset) \
-            & (back_id < k_offset + k_local)
-        scat_local = jnp.where(in_local, back_id - k_offset,
-                               k_local).astype(jnp.int32)
-        bdst_local = jnp.zeros((k_local + 1,), jnp.int32).at[scat_local].max(
-            jnp.where(in_local, nc_dst, 0))[:k_local]
+    is_back, n_back = back_pre
+    bsrc_full, bdst_full = back_tables
+    bdst_local = jax.lax.dynamic_slice(bdst_full, (k_offset,), (k_local,))
 
     bvalid_full = (jnp.arange(k_total) < n_back)
     bvalid_local = (jnp.arange(k_local) + k_offset) < n_back
@@ -227,79 +175,6 @@ def _sweep_window(n_nodes: int, k_total: int, k_local: int, max_rounds: int,
     return has_cycle, witness, n_back, converged
 
 
-def _sweep_arrays(n_nodes: int, max_k: int, max_rounds: int,
-                  rank, nc_src, nc_dst, nc_mask,
-                  chain_nodes, chain_starts, chain_mask, back_pre=None,
-                  back_tables=None):
-    """Core kernel (single window).  Returns (has_cycle, witness_bits,
-    n_backward, converged).
-
-    witness_bits: (max_k,) int8 — 1 for backward edges on some cycle.
-    n_backward: actual number of backward edges found (may exceed max_k —
-    caller must re-batch; we still compute exactly for the first max_k and
-    report overflow via n_backward).
-    """
-    return _sweep_window(n_nodes, max_k, max_k, max_rounds,
-                         rank, nc_src, nc_dst, nc_mask,
-                         chain_nodes, chain_starts, chain_mask,
-                         k_offset=jnp.int32(0), axis_name=None,
-                         back_pre=back_pre, back_tables=back_tables)
-
-
-_sweep = jax.jit(_sweep_arrays,
-                 static_argnames=("n_nodes", "max_k", "max_rounds"))
-
-
-# arrays-first twins of _sweep/_sweep_sharded for the AOT compile-cache
-# seam (compilecache.call dispatches a cached Compiled with the dynamic
-# args alone, so statics must bind by keyword behind the arrays)
-@partial(jax.jit, static_argnames=("n_nodes", "max_k", "max_rounds"))
-def _sweep_kw(rank, nc_src, nc_dst, nc_mask, chain_nodes, chain_starts,
-              chain_mask, *, n_nodes, max_k, max_rounds):
-    return _sweep_arrays(n_nodes, max_k, max_rounds, rank, nc_src,
-                         nc_dst, nc_mask, chain_nodes, chain_starts,
-                         chain_mask)
-
-
-@partial(jax.jit, static_argnames=("n_nodes", "max_k", "max_rounds",
-                                   "mesh", "axis"))
-def _sweep_sharded(n_nodes: int, max_k: int, max_rounds: int, mesh, axis,
-                   rank, nc_src, nc_dst, nc_mask,
-                   chain_nodes, chain_starts, chain_mask):
-    """`_sweep_arrays` with the backward-edge axis sharded over `mesh`
-    (the per-projection form of `parallel/op_shard.py`'s K-window
-    pattern): each device owns max_k / n_shards backward-edge columns
-    and propagates only its label-plane window; the (K, K) meta graph
-    merges with one all_gather.  Same result contract as `_sweep`."""
-    from jax.sharding import PartitionSpec as P
-
-    n_shards = mesh.shape[axis]
-    assert max_k % n_shards == 0, (max_k, n_shards)
-    k_local = max_k // n_shards
-    rep = P()
-
-    @partial(jax.shard_map, mesh=mesh, in_specs=(rep,) * 7,
-             out_specs=(rep, rep, rep, rep))
-    def run(rank_, s_, d_, m_, cn_, cs_, cm_):
-        off = jax.lax.axis_index(axis) * k_local
-        return _sweep_window(n_nodes, max_k, k_local, max_rounds,
-                             rank_, s_, d_, m_, cn_, cs_, cm_,
-                             k_offset=off, axis_name=axis)
-
-    return run(rank, nc_src, nc_dst, nc_mask, chain_nodes, chain_starts,
-               chain_mask)
-
-
-@partial(jax.jit, static_argnames=("n_nodes", "max_k", "max_rounds",
-                                   "mesh", "axis"))
-def _sweep_sharded_kw(rank, nc_src, nc_dst, nc_mask, chain_nodes,
-                      chain_starts, chain_mask, *, n_nodes, max_k,
-                      max_rounds, mesh, axis):
-    return _sweep_sharded(n_nodes, max_k, max_rounds, mesh, axis, rank,
-                          nc_src, nc_dst, nc_mask, chain_nodes,
-                          chain_starts, chain_mask)
-
-
 def _per_edge(vals, fam_lens):
     """Per-family values broadcast over their concatenated edge blocks."""
     return jnp.concatenate([jnp.broadcast_to(vals[f], (L,))
@@ -310,16 +185,16 @@ def enumerate_families(n_nodes: int, k_tab: int, fam_lens,
                        rank, e_src, e_dst, union_mask):
     """The backward-edge enumeration of a union of edge families: ONE
     rank test and ONE E-sized cumsum for every projection that keeps
-    whole families (the single source `projection_scan` and the
-    list-append sweep both call; `project_families` reads one
-    projection off it).
+    whole families — every sweep's (`projection_scan` in the fused
+    programs, `enumerate_backward` for `detect_cycles`;
+    `project_families` reads one projection off it).
 
     Families are concatenated blocks of `fam_lens` edges.  `cum` steps
     by exactly 1 at each union-masked backward edge, so family f's j-th
     backward edge (in edge order) is the first position of f's block
     where `cum` reaches cum_start[f] + j + 1: k_tab binary searches per
-    family build the endpoint tables, where a scatter-max over every
-    edge did before (0.24 s each per projection at 1M-txn TPU shapes).
+    family build the endpoint tables, with no E-sized scatter (a
+    scatter-max over every edge took 0.24 s at 1M-txn TPU shapes).
 
     Returns (back_all (E,) bool, count_f (F,) int32, fam_src, fam_dst
     (F, k_tab) int32), the tables 0 past count_f[f].
@@ -360,10 +235,10 @@ def project_families(fam_lens, max_k: int, union_mask, chain_masks,
     group g iff cinc[g] > 0.  Its family-f backward set IS the union's
     (family masks don't vary per projection, only inclusion), so its
     position-stable enumeration is each kept family's, shifted by the
-    counts of the kept families before it: bit-identical to a cumsum
-    and scatter-max over the projection's own mask (ids >= n_back stay
-    0).  Returns (nc_mask (E,), chain_mask (C,), back_pre (is_back,
-    n_back), back_tables (bsrc, bdst) (max_k,)).
+    counts of the kept families before it: the enumeration of the
+    projection's own mask in edge order (ids >= n_back stay 0).
+    Returns (nc_mask (E,), chain_mask (C,), back_pre (is_back, n_back),
+    back_tables (bsrc, bdst) (max_k,)).
     """
     back_all, count_f, fam_src, fam_dst = enumeration
     k_tab = fam_src.shape[1]
@@ -389,88 +264,6 @@ def project_families(fam_lens, max_k: int, union_mask, chain_masks,
             (back_all & keep, jnp.sum(count_f * inc)), (bsrc, bdst))
 
 
-def projection_scan(n_nodes: int, max_k: int, max_rounds: int,
-                    rank, e_src, e_dst, fam_masks, inc_stack,
-                    chain_nodes, chain_starts, chain_masks, cinc_stack,
-                    sweep=None):
-    """Scan `_sweep_arrays` over projections given per-family masks and
-    per-projection family-include flags — the single-sourced hoisted
-    form shared by device_core.core_check and device_rw.rw_core_check.
-
-    `sweep` (optional) replaces the single-window `_sweep_arrays` call
-    with a caller-supplied kernel of signature (rank, e_src, e_dst,
-    mask, chain_nodes, chain_starts, chain_mask, back_pre,
-    back_tables) -> (has, witness, n_back, converged), where back_pre
-    is (is_back, n_back) and back_tables the (max_k,) (bsrc, bdst)
-    endpoint pair of `project_families` — how the K-windowed sharded
-    paths (`parallel/op_shard.py`, `parallel/hybrid.py`) reuse this
-    scan with `_sweep_window` inside shard_map while keeping the
-    hoisted enumeration (VERDICT r04 item 2: the sharded sweep
-    previously re-materialized (5, E) mask stacks and ran 5 E-sized
-    cumsums).
-
-    Instead of materialized (P, E)/(P, C) mask stacks and an E-sized
-    cumsum per projection, the scan consumes tiny include matrices:
-    per-projection masks are `family_mask & include`, and the
-    backward-edge enumeration is `enumerate_families`' ONE, read per
-    projection by `project_families`.  Measured effect at 1M txns on
-    CPU: fused check 7.98 s -> 5.18 s and compile 28.8 s -> 7.9 s
-    (PROFILE.md §0b).
-
-    fam_masks: per-family (E_f,) bool masks, concat order == e_src.
-    inc_stack: (P, F) int32 — family f included in projection p.
-    chain_masks: per-chain-group (C_g,) bool, concat order ==
-    chain_nodes.  cinc_stack: (P, G) int32.
-    Returns (conv_all, overflow, cyc_bits (P,) int32).
-    """
-    fam_lens = [int(m.shape[0]) for m in fam_masks]
-    union_mask = jnp.concatenate(list(fam_masks))
-    enum = enumerate_families(n_nodes, max_k, fam_lens, rank, e_src, e_dst,
-                              union_mask)
-    count_f = enum[1]
-
-    def proj_body(carry, mc):
-        conv_all, overflow = carry
-        inc, cinc = mc
-        m, cm, back_pre, tables = project_families(
-            fam_lens, max_k, union_mask, chain_masks, enum, inc, cinc)
-        if sweep is None:
-            has, _, n_back_out, conv = _sweep_arrays(
-                n_nodes, max_k, max_rounds, rank, e_src, e_dst, m,
-                chain_nodes, chain_starts, cm, back_pre=back_pre,
-                back_tables=tables)
-        else:
-            has, _, n_back_out, conv = sweep(
-                rank, e_src, e_dst, m, chain_nodes, chain_starts, cm,
-                back_pre, tables)
-        carry = (conv_all & conv,
-                 jnp.maximum(overflow,
-                             jnp.maximum(n_back_out - max_k, 0)))
-        return carry, has.astype(jnp.int32)
-
-    # carry init derives from traced inputs so its varying-axis type
-    # matches the body outputs under shard_map/vmap
-    zero0 = e_src[0] * 0
-    n_proj = int(inc_stack.shape[0])
-
-    def run_scan(_):
-        (conv_all, overflow), cyc_bits = jax.lax.scan(
-            proj_body, (zero0 == 0, zero0), (inc_stack, cinc_stack))
-        return conv_all, overflow, cyc_bits
-
-    def no_backward(_):
-        # zero backward edges across the FULL family union: every
-        # projection's backward set is a subset, so all P projections
-        # are DAGs — converged, no overflow, no cycles.  The common
-        # case for valid histories; skipping the scan saves P rounds of
-        # E-sized masking/enumeration.  (Under vmap this cond lowers to
-        # select and both branches still run — batched paths keep their
-        # old cost, never a new one.)
-        return zero0 == 0, zero0, jnp.zeros((n_proj,), jnp.int32) + zero0
-
-    return jax.lax.cond(jnp.sum(count_f) > 0, run_scan, no_backward,
-                        operand=None)
-
 #: budget ceilings shared by every sweep driver (detect_cycles here,
 #: grow_until_exact in device_core): past these, callers fall back to
 #: the host oracle rather than approximate
@@ -482,14 +275,18 @@ MAX_ROUNDS_CAP = 1024
 class FamilyGraph:
     """A sweep graph whose projections each keep whole edge families and
     chain groups (device arrays): the list-append checker's, whose
-    projections are sets of dependency rels.
+    projections are sets of dependency rels, or a plain graph of one
+    family (`plain`).
 
     Non-chain edges are families concatenated in blocks of `fam_lens`
     edges under one `base_mask`; chains are groups concatenated in
-    `chain_nodes` order, one mask each.  `enumerate_backward` makes the
-    union's backward-edge enumeration once (`enumeration`); each
-    `project(...)` is then swept by `detect_cycles` with no E-sized
-    rank test, cumsum or scatter of its own.
+    `chain_nodes` order, one mask each: chain_nodes[i] -> chain_nodes[i
+    + 1] within a segment (`chain_starts` flags segment heads).  Ranks
+    are unique per node and increase along chains; forward = rank
+    increases.  `enumerate_backward` makes the union's backward-edge
+    enumeration once (`enumeration`); each `project(...)` is then swept
+    by `detect_cycles` with no E-sized rank test, cumsum or scatter of
+    its own.
     """
 
     n_nodes: int
@@ -508,6 +305,18 @@ class FamilyGraph:
     #: new graph without it)
     _host_back: Optional[np.ndarray] = dataclasses.field(
         default=None, init=False, repr=False, compare=False)
+
+    @classmethod
+    def plain(cls, n_nodes: int, rank, src, dst, mask) -> "FamilyGraph":
+        """One family of edges (src -> dst where `mask`) and one empty
+        chain group."""
+        return cls(n_nodes=n_nodes, rank=jnp.asarray(rank),
+                   nc_src=jnp.asarray(src), nc_dst=jnp.asarray(dst),
+                   base_mask=jnp.asarray(mask),
+                   fam_lens=(int(src.shape[0]),),
+                   chain_nodes=jnp.zeros(0, jnp.int32),
+                   chain_starts=jnp.zeros(0, bool),
+                   chain_masks=(jnp.zeros(0, bool),))
 
     @property
     def k_tab(self) -> int:
@@ -550,6 +359,91 @@ class FamilyProjection:
             if keep])
 
 
+def replicated(fn, mesh):
+    """`fn` under a shard_map over `mesh` with every input and output
+    replicated: each device runs all of it, and a sweep inside sweeps
+    that device's window of the backward-edge axis."""
+    from jax.sharding import PartitionSpec as P
+
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P())
+
+
+def _sweep_families(g: FamilyGraph, max_k: int, max_rounds: int, inc, cinc,
+                    axis: Optional[str] = None, n_shards: int = 1):
+    """`_sweep_window` over one projection of an enumerated graph, read
+    off the union's enumeration by `project_families`: only O(max_k)
+    and elementwise work before the `n_back > 0` cond.  The one entry
+    to the K-window: with `axis` (inside a shard_map over a mesh axis
+    of `n_shards` devices) each device sweeps max_k // n_shards
+    backward-edge columns at axis_index * k_local."""
+    if axis is None:
+        k_local, k_offset = max_k, jnp.int32(0)
+    else:
+        assert max_k % n_shards == 0, (max_k, n_shards)
+        k_local = max_k // n_shards
+        k_offset = jax.lax.axis_index(axis) * k_local
+    nc_mask, chain_mask, back_pre, tables = project_families(
+        g.fam_lens, max_k, g.base_mask, g.chain_masks, g.enumeration, inc,
+        cinc)
+    return _sweep_window(g.n_nodes, max_k, k_local, max_rounds, g.rank,
+                         g.nc_src, g.nc_dst, nc_mask, g.chain_nodes,
+                         g.chain_starts, chain_mask, k_offset, back_pre,
+                         tables, axis_name=axis)
+
+
+def projection_scan(g: FamilyGraph, max_k: int, max_rounds: int,
+                    inc_stack, cinc_stack, axis: Optional[str] = None,
+                    n_shards: int = 1):
+    """Sweep projections of `g` inside a traced program: one
+    `enumerate_families` of the union, then one sweep instantiation
+    scanned over the projections — the form of the fused checks
+    (device_core, device_rw).  A Python loop would inline a while_loop
+    kernel per projection (125.8 s of XLA compile at 100k-txn shapes);
+    the scan keeps one (N, max_k) label plane live.
+
+    inc_stack: (P, F) int32 — family f kept in projection p;
+    cinc_stack: (P, G) int32 — chain group g kept.  `axis` and
+    `n_shards` as in `_sweep_families`.  Returns (conv_all, overflow,
+    cyc_bits (P,) int32).
+    """
+    g = dataclasses.replace(g, enumeration=enumerate_families(
+        g.n_nodes, max_k, g.fam_lens, g.rank, g.nc_src, g.nc_dst,
+        g.base_mask))
+    count_f = g.enumeration[1]
+
+    def proj_body(carry, mc):
+        conv_all, overflow = carry
+        inc, cinc = mc
+        has, _, n_back_out, conv = _sweep_families(
+            g, max_k, max_rounds, inc, cinc, axis=axis, n_shards=n_shards)
+        carry = (conv_all & conv,
+                 jnp.maximum(overflow,
+                             jnp.maximum(n_back_out - max_k, 0)))
+        return carry, has.astype(jnp.int32)
+
+    # carry init derives from traced inputs so its varying-axis type
+    # matches the body outputs under shard_map/vmap
+    zero0 = g.nc_src[0] * 0
+    n_proj = int(inc_stack.shape[0])
+
+    def run_scan(_):
+        (conv_all, overflow), cyc_bits = jax.lax.scan(
+            proj_body, (zero0 == 0, zero0), (inc_stack, cinc_stack))
+        return conv_all, overflow, cyc_bits
+
+    def no_backward(_):
+        # zero backward edges across the FULL family union: every
+        # projection's backward set is a subset, so all P projections
+        # are DAGs — converged, no overflow, no cycles.  The common
+        # case for valid histories; skipping the scan saves P rounds of
+        # E-sized masking.  (Under vmap this cond lowers to select and
+        # both branches still run.)
+        return zero0 == 0, zero0, jnp.zeros((n_proj,), jnp.int32) + zero0
+
+    return jax.lax.cond(jnp.sum(count_f) > 0, run_scan, no_backward,
+                        operand=None)
+
+
 @partial(jax.jit, static_argnames=("n_nodes", "k_tab", "fam_lens", "mesh"))
 def _enumerate_kw(rank, nc_src, nc_dst, base_mask, *, n_nodes, k_tab,
                   fam_lens, mesh=None):
@@ -559,10 +453,7 @@ def _enumerate_kw(rank, nc_src, nc_dst, base_mask, *, n_nodes, k_tab,
     if mesh is not None:
         # every chip enumerates the whole union, so what each projection's
         # shard_map sweep reads goes in replicated
-        from jax.sharding import PartitionSpec as P
-
-        run = jax.shard_map(run, mesh=mesh, in_specs=(P(),) * 4,
-                            out_specs=(P(),) * 4)
+        run = replicated(run, mesh)
     return run(rank, nc_src, nc_dst, base_mask)
 
 
@@ -595,21 +486,9 @@ def enumerate_backward(g: FamilyGraph, k_tab: int = 128, mesh=None,
     return dataclasses.replace(g, enumeration=tuple(enum))
 
 
-def _sweep_families(n_nodes, max_k, k_local, max_rounds, fam_lens, rank,
-                    nc_src, nc_dst, base_mask, enumeration, inc, chain_nodes,
-                    chain_starts, chain_masks, cinc, k_offset,
-                    axis_name=None):
-    """`_sweep_window` over one projection of a `FamilyGraph`, read off
-    the union's enumeration by `project_families`: only O(max_k) and
-    elementwise work before the `n_back > 0` cond."""
-    nc_mask, chain_mask, back_pre, tables = project_families(
-        fam_lens, max_k, base_mask, chain_masks, enumeration, inc, cinc)
-    return _sweep_window(n_nodes, max_k, k_local, max_rounds, rank, nc_src,
-                         nc_dst, nc_mask, chain_nodes, chain_starts,
-                         chain_mask, k_offset, axis_name=axis_name,
-                         back_pre=back_pre, back_tables=tables)
-
-
+# arrays first and statics by keyword: the AOT compile-cache seam
+# (compilecache.call dispatches a cached Compiled with the dynamic args
+# alone)
 @partial(jax.jit, static_argnames=("n_nodes", "max_k", "max_rounds",
                                    "fam_lens", "mesh", "axis"))
 def _sweep_families_kw(rank, nc_src, nc_dst, base_mask, enumeration, inc,
@@ -617,55 +496,38 @@ def _sweep_families_kw(rank, nc_src, nc_dst, base_mask, enumeration, inc,
                        n_nodes, max_k, max_rounds, fam_lens, mesh=None,
                        axis=None):
     """One projection's sweep: single-window, or with `mesh` the
-    backward-edge axis sharded over it as `_sweep_sharded` does, every
-    input replicated."""
-    args = (rank, nc_src, nc_dst, base_mask, enumeration, inc, chain_nodes,
-            chain_starts, chain_masks, cinc)
-    if mesh is None:
-        return _sweep_families(n_nodes, max_k, max_k, max_rounds, fam_lens,
-                               *args, k_offset=jnp.int32(0))
-    from jax.sharding import PartitionSpec as P
+    backward-edge axis sharded over it, every input replicated."""
+    n_shards = mesh.shape[axis] if mesh is not None else 1
 
-    n_shards = mesh.shape[axis]
-    assert max_k % n_shards == 0, (max_k, n_shards)
-    k_local = max_k // n_shards
+    def run(rank, nc_src, nc_dst, base_mask, enumeration, inc, chain_nodes,
+            chain_starts, chain_masks, cinc):
+        g = FamilyGraph(n_nodes, rank, nc_src, nc_dst, base_mask, fam_lens,
+                        chain_nodes, chain_starts, chain_masks, enumeration)
+        return _sweep_families(g, max_k, max_rounds, inc, cinc, axis=axis,
+                               n_shards=n_shards)
 
-    @partial(jax.shard_map, mesh=mesh, in_specs=(P(),) * len(args),
-             out_specs=(P(),) * 4)
-    def run(*a):
-        off = jax.lax.axis_index(axis) * k_local
-        return _sweep_families(n_nodes, max_k, k_local, max_rounds,
-                               fam_lens, *a, k_offset=off, axis_name=axis)
-
-    return run(*args)
+    if mesh is not None:
+        run = replicated(run, mesh)
+    return run(rank, nc_src, nc_dst, base_mask, enumeration, inc,
+               chain_nodes, chain_starts, chain_masks, cinc)
 
 
-def _run_sweep(g, max_k: int, max_rounds: int, mesh, axis: str):
+def _run_sweep(g: FamilyProjection, max_k: int, max_rounds: int, mesh,
+               axis: str):
     """Dispatch one sweep program through the AOT compile cache: verifier
     sweep chunks and checker projections pad to pow2 (N, E) classes, so
     maintenance rounds and probes share persisted executables."""
     from jepsen_tpu import compilecache
 
-    if isinstance(g, FamilyProjection):
-        u = g.graph
-        return compilecache.call(
-            "cycle-sweep.families", _sweep_families_kw, u.rank, u.nc_src,
-            u.nc_dst, u.base_mask, u.enumeration,
-            jnp.asarray(g.inc, jnp.int32), u.chain_nodes, u.chain_starts,
-            tuple(u.chain_masks), jnp.asarray(g.cinc, jnp.int32),
-            n_nodes=u.n_nodes, max_k=max_k, max_rounds=max_rounds,
-            fam_lens=tuple(u.fam_lens), mesh=mesh,
-            axis=axis if mesh is not None else None)
-    if mesh is not None:
-        return compilecache.call(
-            "cycle-sweep.sharded", _sweep_sharded_kw, g.rank, g.nc_src,
-            g.nc_dst, g.nc_mask, g.chain_nodes, g.chain_starts,
-            g.chain_mask, n_nodes=g.n_nodes, max_k=max_k,
-            max_rounds=max_rounds, mesh=mesh, axis=axis)
+    u = g.graph
     return compilecache.call(
-        "cycle-sweep", _sweep_kw, g.rank, g.nc_src, g.nc_dst, g.nc_mask,
-        g.chain_nodes, g.chain_starts, g.chain_mask, n_nodes=g.n_nodes,
-        max_k=max_k, max_rounds=max_rounds)
+        "cycle-sweep.families", _sweep_families_kw, u.rank, u.nc_src,
+        u.nc_dst, u.base_mask, u.enumeration,
+        jnp.asarray(g.inc, jnp.int32), u.chain_nodes, u.chain_starts,
+        tuple(u.chain_masks), jnp.asarray(g.cinc, jnp.int32),
+        n_nodes=u.n_nodes, max_k=max_k, max_rounds=max_rounds,
+        fam_lens=tuple(u.fam_lens), mesh=mesh,
+        axis=axis if mesh is not None else None)
 
 
 @dataclasses.dataclass
@@ -676,7 +538,7 @@ class SweepResult:
     converged: bool
 
 
-def detect_cycles(g: SweepGraph | FamilyProjection, max_k: int = 128,
+def detect_cycles(g: FamilyGraph | FamilyProjection, max_k: int = 128,
                   max_rounds: int = 64, deadline=None, mesh=None,
                   axis: str = "batch") -> SweepResult:
     """Run the sweep; rebatch automatically if backward edges exceed max_k.
@@ -685,10 +547,10 @@ def detect_cycles(g: SweepGraph | FamilyProjection, max_k: int = 128,
     converged=True.  Witnesses identify backward edges on cycles (for the
     first max_k; enough to hand the host a subgraph to classify).
 
-    `g` is a plain `SweepGraph`, swept by a program that enumerates its
-    backward edges itself, or a `FamilyProjection` of a `FamilyGraph`
-    enumerated on the same `mesh` (`enumerate_backward`), whose program
-    reads them off the union's enumeration.  Both give the same result.
+    `g` is a projection of a `FamilyGraph`, or a `FamilyGraph` meaning
+    all of it.  A graph not enumerated on the same `mesh`
+    (`enumerate_backward`), or whose tables are narrower than max_k, is
+    enumerated here first.
 
     `deadline` (a `resilience.Deadline`) is polled before each grow-
     retry — the budget-doubling fixpoint is this driver's unbounded
@@ -703,14 +565,16 @@ def detect_cycles(g: SweepGraph | FamilyProjection, max_k: int = 128,
         deadline.check("cycle-sweep")
     from jepsen_tpu import telemetry
 
+    if isinstance(g, FamilyGraph):
+        g = g.project((1,) * len(g.fam_lens), (1,) * len(g.chain_masks))
     if mesh is not None and mesh.devices.size > 1:
         n_shards = mesh.shape[axis]
         if max_k % n_shards:
             max_k = ((max_k // n_shards) + 1) * n_shards
     else:
         mesh = None
-    if isinstance(g, FamilyProjection) and g.graph.k_tab < max_k:
-        # a retry outgrew the union's endpoint tables: enumerate again
+    if g.graph.k_tab < max_k:
+        # not enumerated yet, or a retry outgrew the endpoint tables
         g = dataclasses.replace(
             g, graph=enumerate_backward(g.graph, max_k, mesh, axis))
     # one span per program run, ending at the n_back read that syncs it
@@ -756,24 +620,16 @@ def detect_cycles(g: SweepGraph | FamilyProjection, max_k: int = 128,
         # the witness bits are diagonal(closure) & bvalid and has_cycle is
         # their any(): without a cycle there is no bit to map, so nothing
         # is copied from the device
-        base = g.graph if isinstance(g, FamilyProjection) else g
         wit_ids = np.zeros(0, np.int64)
         host_copy = False
         if has:
             # map witness backward-edge ids back to edge-array positions
-            if isinstance(g, FamilyProjection):
-                host_copy = base._host_back is None
-                back_pos = g.host_backward()
-            else:
-                rank = np.asarray(g.rank)
-                src = np.clip(np.asarray(g.nc_src), 0, g.n_nodes - 1)
-                dst = np.clip(np.asarray(g.nc_dst), 0, g.n_nodes - 1)
-                back_pos = np.nonzero(np.asarray(g.nc_mask)
-                                      & (rank[src] >= rank[dst]))[0]
+            host_copy = g.graph._host_back is None
+            back_pos = g.host_backward()
             wit = np.asarray(wit)
             wit_ids = back_pos[np.nonzero(wit[:len(back_pos)])[0]]
         if telemetry.enabled():
-            sp.set_attr(edges=int(base.nc_src.shape[0]),
+            sp.set_attr(edges=int(g.graph.nc_src.shape[0]),
                         witnesses=len(wit_ids), mapped=has,
                         host_copy=host_copy)
     return SweepResult(has_cycle=has, witness_edge_ids=wit_ids,

@@ -31,20 +31,18 @@ from functools import partial
 from typing import List, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jepsen_tpu import resilience
+from jepsen_tpu.checkers.elle.device_core import _verdict
 from jepsen_tpu.checkers.elle.device_infer import infer
 from jepsen_tpu.history.soa import PackedTxns
-from jepsen_tpu.ops.cycle_sweep import _sweep_window
 from jepsen_tpu.parallel.batch import (
     batch_caps,
     pad_batch,
     summarize_batch_bits,
 )
-from jepsen_tpu.parallel.op_shard import projection_sweep_bits
 
 
 def make_hybrid_mesh(n_dcn: int, n_k: int, devices=None) -> Mesh:
@@ -56,27 +54,14 @@ def make_hybrid_mesh(n_dcn: int, n_k: int, devices=None) -> Mesh:
 @partial(jax.jit, static_argnames=("n_keys", "mesh", "max_k", "max_rounds"))
 def _hybrid_core(batch, n_keys: int, mesh: Mesh, max_k: int = 128,
                  max_rounds: int = 64):
-    n_k = mesh.shape["k"]
-    assert max_k % n_k == 0, (max_k, n_k)
-    k_local = max_k // n_k
-    T = batch.txn_type.shape[1]
-
     bspec = P("dcn")
 
     @partial(jax.shard_map, mesh=mesh, in_specs=(bspec,),
              out_specs=(bspec, bspec))
     def rows(b):
         def one(h):
-            out = infer(h, n_keys)
-
-            def sweep(rank_, e_src_, e_dst_, m_, cn_, cs_, cm_, bp_, bt_):
-                off = jax.lax.axis_index("k") * k_local
-                return _sweep_window(2 * T, max_k, k_local, max_rounds,
-                                     rank_, e_src_, e_dst_, m_, cn_, cs_,
-                                     cm_, k_offset=off, axis_name="k",
-                                     back_pre=bp_, back_tables=bt_)
-
-            return projection_sweep_bits(out, max_k, sweep)
+            return _verdict(infer(h, n_keys), max_k, max_rounds, axis="k",
+                            n_shards=mesh.shape["k"])
 
         return jax.vmap(one)(b)
 

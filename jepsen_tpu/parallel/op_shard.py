@@ -43,102 +43,32 @@ from functools import partial
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jepsen_tpu.checkers.elle.device_core import (
     COUNT_NAMES,
-    PROJECTIONS,
+    _verdict,
     grow_until_exact,
 )
 from jepsen_tpu.checkers.elle.device_infer import PaddedLA, infer, pad_packed
 from jepsen_tpu.history.soa import PackedTxns
-from jepsen_tpu.ops.cycle_sweep import _sweep_window
-
-
-def projection_sweep_bits(out, max_k: int, sweep):
-    """The 5-projection scan over an inferred edge set, with `sweep` a
-    callable (rank, e_src, e_dst, mask, chain_nodes, chain_starts,
-    chain_mask, back_pre, back_tables) -> (has_cycle, witness, n_back,
-    converged); back_pre is the hoisted backward enumeration (is_back,
-    n_back) and back_tables the searchsorted-built (max_k,) (bsrc,
-    bdst) endpoint pair that `_sweep_window` consumes directly.
-
-    One sweep instantiation scanned over the 5 projections — same
-    compile-time + label-plane-memory rationale as device_core.core_check
-    (5 inlined while_loop kernels measured 125.8 s of XLA compile at
-    100k-txn shapes in round 2).  Shared by the K-axis sharded path and
-    the 2D hybrid (dcn x k) path (`parallel/hybrid.py`).  Since round 5
-    this delegates to `cycle_sweep.projection_scan` — family-include
-    flags plus ONE shared E-sized backward cumsum — instead of
-    materializing (5, E)/(5, C) mask stacks and re-running 5 cumsums
-    (VERDICT r04 item 2; the single-device paths migrated in round 4,
-    PROFILE.md §0b).
-    """
-    edges = out["edges"]
-    chains = out["chains"]
-    rank = jnp.concatenate([out["ranks"]["txn"], out["ranks"]["barrier"]])
-    e_src = jnp.concatenate([edges[k][0] for k in ("ww", "wr", "rw", "tb",
-                                                   "bt")])
-    e_dst = jnp.concatenate([edges[k][1] for k in ("ww", "wr", "rw", "tb",
-                                                   "bt")])
-
-    pc_nodes, pc_starts, pc_mask = chains["process"]
-    bc_nodes, bc_starts, bc_mask = chains["barrier"]
-    chain_nodes = jnp.concatenate([pc_nodes, bc_nodes])
-    chain_starts = jnp.concatenate([pc_starts, bc_starts])
-
-    from jepsen_tpu.checkers.elle.device_core import (
-        chain_include_stack,
-        proj_include_stack,
-    )
-    from jepsen_tpu.ops.cycle_sweep import projection_scan
-
-    # max_rounds is owned by the sweep closure (unused when sweep is set)
-    conv_all, overflow, cyc_bits = projection_scan(
-        rank.shape[0], max_k, 0, rank, e_src, e_dst,
-        [edges[k][2] for k in ("ww", "wr", "rw", "tb", "bt")],
-        proj_include_stack(PROJECTIONS),
-        chain_nodes, chain_starts, [pc_mask, bc_mask],
-        chain_include_stack(PROJECTIONS), sweep=sweep)
-
-    counts = jnp.stack([out["counts"][n].astype(jnp.int32)
-                        for n in COUNT_NAMES])
-    bits = jnp.concatenate(
-        [counts, cyc_bits, conv_all.astype(jnp.int32)[None]])
-    return bits, overflow
+from jepsen_tpu.ops.cycle_sweep import replicated
 
 
 @partial(jax.jit,
          static_argnames=("n_keys", "mesh", "axis", "max_k", "max_rounds"))
 def _core_check_sharded(h: PaddedLA, n_keys: int, mesh: Mesh, axis: str,
                         max_k: int = 128, max_rounds: int = 64):
-    """core_check with the sweep's backward-edge axis sharded over the
-    mesh.  Same bit layout as device_core.core_check."""
-    n_shards = mesh.shape[axis]
-    assert max_k % n_shards == 0, (max_k, n_shards)
-    k_local = max_k // n_shards
-
+    """core_check with inference under GSPMD and the verdict in one
+    shard_map, its inputs replicated: every device enumerates the whole
+    union and sweeps its window of the backward-edge axis.  Same bit
+    layout as device_core.core_check."""
     out = infer(h, n_keys)
-    T = h.txn_type.shape[0]
-    rep = P()
-
-    @partial(jax.shard_map, mesh=mesh,
-             in_specs=(rep,) * 11, out_specs=(rep, rep, rep, rep))
-    def sharded_sweep(rank_, e_src_, e_dst_, m_, cn_, cs_, cm_,
-                      ib_, nb_, bsrc_, bdst_):
-        off = jax.lax.axis_index(axis) * k_local
-        return _sweep_window(2 * T, max_k, k_local, max_rounds,
-                             rank_, e_src_, e_dst_, m_, cn_, cs_, cm_,
-                             k_offset=off, axis_name=axis,
-                             back_pre=(ib_, nb_),
-                             back_tables=(bsrc_, bdst_))
-
-    return projection_sweep_bits(
-        out, max_k,
-        lambda r, s, d, m, cn, cs, cm, bp, bt: sharded_sweep(
-            r, s, d, m, cn, cs, cm, *bp, *bt))
+    out = {k: out[k] for k in ("counts", "edges", "chains", "ranks")}
+    return replicated(
+        partial(_verdict, max_k=max_k, max_rounds=max_rounds, axis=axis,
+                n_shards=mesh.shape[axis]), mesh)(out)
 
 
 def shard_padded(h: PaddedLA, mesh: Mesh, axis: str = "dp"

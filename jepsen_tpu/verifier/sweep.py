@@ -136,7 +136,7 @@ def _dispatch(regions: List[Dict[str, Any]],
     """One block-diagonal `detect_cycles` over every region.  Returns
     ``(converged, hit_blocks)`` — blocks whose region carries a
     backward-edge witness (a cycle passes through them)."""
-    from jepsen_tpu.ops.cycle_sweep import SweepGraph, detect_cycles
+    from jepsen_tpu.ops.cycle_sweep import FamilyGraph, detect_cycles
 
     node_off: List[int] = []
     edge_bounds: List[int] = [0]
@@ -157,18 +157,10 @@ def _dispatch(regions: List[Dict[str, Any]],
     e_pad = _pow2(max(2, n_edges))
     mask = np.zeros(e_pad, bool)
     mask[:n_edges] = True
-    g = SweepGraph(
-        n_nodes=n_pad,
-        rank=np.arange(n_pad, dtype=np.int32),
-        nc_src=np.concatenate(
-            [src, np.zeros(e_pad - n_edges, np.int32)]),
-        nc_dst=np.concatenate(
-            [dst, np.zeros(e_pad - n_edges, np.int32)]),
-        nc_mask=mask,
-        chain_nodes=np.zeros(0, np.int32),
-        chain_starts=np.zeros(0, bool),
-        chain_mask=np.zeros(0, bool),
-    )
+    g = FamilyGraph.plain(
+        n_pad, np.arange(n_pad, dtype=np.int32),
+        np.concatenate([src, np.zeros(e_pad - n_edges, np.int32)]),
+        np.concatenate([dst, np.zeros(e_pad - n_edges, np.int32)]), mask)
     with telemetry.span("verifier.sweep", batched=True,
                         sessions=n_sessions, regions=len(regions),
                         nodes=n_nodes, edges=n_edges):

@@ -1,18 +1,23 @@
-"""The list-append sweep over one backward-edge enumeration per check
-(`cycle_sweep.FamilyGraph`, `enumerate_backward`) against the sweep of a
-plain `SweepGraph` per projection, which enumerates its own backward
-edges: the same verdict, backward count, convergence and witness edge
-ids on every graph, one chip and sharded over a 4-device mesh.
+"""The cycle sweep over one backward-edge enumeration per graph
+(`cycle_sweep.FamilyGraph`, `enumerate_backward`) against a host
+reference that shares no sweep code (a numpy rank test and host
+reachability over each projection): the same verdict, backward count,
+convergence and witness edge ids on every graph, in the list-append
+checker's five-family form and in the one-family form of `txn_cycles`
+and the verifier, one chip and sharded over a 4-device mesh.
 
 Also: `projection_scan`'s outputs on one fixed graph, as they were
 before it shared `enumerate_families`; the union tables against a numpy
 enumeration; no edge-sized scatter or rank gather in the per-projection
-program outside its `n_back > 0` cond; one `sweep.enumerate` span per
+program outside its `n_back > 0` cond, and no edge scatter in the rw
+fused program's version sweep; one `sweep.enumerate` span per
 list-append check.  The witness map against a numpy map of the sweep
 program's own witness bits, and its spans: a map (and one host copy of
 the union's enumeration per check) only after a sweep that found a
 cycle.
 """
+
+import types
 
 import jax
 import jax.numpy as jnp
@@ -122,28 +127,59 @@ def _family_graph(gr):
         chain_masks=(jnp.asarray(pm), jnp.asarray(bm)))
 
 
-def _plain_graph(gr, inc, cinc):
-    """The projection as today's path takes it: eager masks."""
-    (pn, ps, pm), (bn, bs, bm) = gr["chains"]
+def _projection_edges(gr, inc, cinc):
+    """(src, dst, mask) of the projection: every family edge, masked in
+    where its family is kept, then the edges the kept chain groups imply
+    (each masked-in chain node to the next in its segment)."""
     keep = np.repeat(np.asarray(inc) > 0, gr["fam_lens"])
-    return cs.SweepGraph(
-        n_nodes=len(gr["rank"]), rank=jnp.asarray(gr["rank"]),
-        nc_src=jnp.asarray(gr["src"]), nc_dst=jnp.asarray(gr["dst"]),
-        nc_mask=jnp.asarray(gr["mask"] & keep),
-        chain_nodes=jnp.asarray(np.concatenate([pn, bn]).astype(np.int32)),
-        chain_starts=jnp.asarray(np.concatenate([ps, bs])),
-        chain_mask=jnp.asarray(np.concatenate([pm & bool(cinc[0]),
-                                               bm & bool(cinc[1])])))
+    src, dst, mask = [gr["src"]], [gr["dst"]], [gr["mask"] & keep]
+    for on, (nodes, starts, m) in zip(cinc, gr["chains"]):
+        if on:
+            idx = np.nonzero(m)[0]
+            seg = np.cumsum(starts)[idx]
+            same = seg[1:] == seg[:-1]
+            src.append(nodes[idx[:-1][same]])
+            dst.append(nodes[idx[1:][same]])
+            mask.append(np.ones(int(same.sum()), bool))
+    return (np.concatenate(src).astype(np.int32),
+            np.concatenate(dst).astype(np.int32), np.concatenate(mask))
+
+
+def _host_sweep(rank, src, dst, mask):
+    """The reference: backward edges by a numpy rank test, and a
+    backward edge u -> w a witness iff host reachability over the masked
+    edges leads from w to u.  Past MAX_K_CAP backward edges the sweep
+    hands the check to the host: converged False, no witnesses."""
+    from scipy.sparse import csr_matrix
+    from scipy.sparse.csgraph import breadth_first_order
+
+    back = np.nonzero(mask & (rank[src] >= rank[dst]))[0]
+    if len(back) > cs.MAX_K_CAP:
+        return types.SimpleNamespace(
+            has_cycle=None, witness_edge_ids=np.zeros(0, np.int64),
+            n_backward=len(back), converged=False)
+    n = len(rank)
+    adj = csr_matrix((np.ones(int(mask.sum())), (src[mask], dst[mask])),
+                     shape=(n, n))
+    wit = np.asarray([e for e in back if src[e] in breadth_first_order(
+        adj, dst[e], return_predecessors=False)], np.int64)
+    return types.SimpleNamespace(has_cycle=len(wit) > 0,
+                                 witness_edge_ids=wit,
+                                 n_backward=len(back), converged=True)
 
 
 def _mesh(n):
     return Mesh(np.array(jax.devices()[:n]), ("batch",)) if n > 1 else None
 
 
-def _same(a, b):
-    assert (a.has_cycle, a.n_backward, a.converged) == \
-        (b.has_cycle, b.n_backward, b.converged)
-    assert np.array_equal(a.witness_edge_ids, b.witness_edge_ids)
+def _same(got, want):
+    """`got` as the host reference `want` has it (a sweep handed to the
+    host has no verdict to compare)."""
+    assert (got.n_backward, got.converged) == \
+        (want.n_backward, want.converged)
+    if want.converged:
+        assert got.has_cycle is want.has_cycle
+    assert np.array_equal(got.witness_edge_ids, want.witness_edge_ids)
 
 
 def _numpy_back(gr, inc=(1, 1, 1, 1, 1)):
@@ -152,18 +188,26 @@ def _numpy_back(gr, inc=(1, 1, 1, 1, 1)):
     return gr["mask"] & keep & (rank[src] >= rank[dst])
 
 
+@pytest.mark.parametrize("form", ["families", "one-family"])
 @pytest.mark.parametrize("chips", [1, 4])
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_family_sweep_matches_plain_sweep(case, chips):
+def test_sweep_matches_the_host_reference(case, chips, form):
+    """Each projection swept as a projection of the five-family graph,
+    or as its own one-family graph (`FamilyGraph.plain`) whose edges are
+    the projection's, chain edges included."""
     seed, n_back, cycle = CASES[case]
     gr = _graph(seed, n_back, cycle)
     assert int(_numpy_back(gr).sum()) == n_back + 2 * cycle
     mesh = _mesh(chips)
     fam = cs.enumerate_backward(_family_graph(gr), mesh=mesh)
     for inc, cinc in PROJECTIONS:
-        got = cs.detect_cycles(fam.project(inc, cinc), mesh=mesh)
-        want = cs.detect_cycles(_plain_graph(gr, inc, cinc), mesh=mesh)
-        _same(got, want)
+        src, dst, mask = _projection_edges(gr, inc, cinc)
+        if form == "families":
+            g = fam.project(inc, cinc)
+        else:
+            g = cs.FamilyGraph.plain(N_NODES, gr["rank"], src, dst, mask)
+        got = cs.detect_cycles(g, mesh=mesh)
+        _same(got, _host_sweep(gr["rank"], src, dst, mask))
         assert got.converged
         assert got.n_backward == int(_numpy_back(gr, inc).sum())
         # the planted cycles: where families 0 and 2 are kept, and where
@@ -193,20 +237,21 @@ def test_a_retry_past_the_tables_enumerates_again(chips):
     assert names == ["sweep.call", "sweep.enumerate", "sweep.call",
                      "sweep.witness-map"]
     assert c.roots[1].attrs["k_tab"] == 512 == c.roots[2].attrs["max_k"]
-    _same(got, cs.detect_cycles(_plain_graph(gr, inc, cinc), mesh=mesh))
+    _same(got, _host_sweep(gr["rank"], *_projection_edges(gr, inc, cinc)))
 
 
 @pytest.mark.parametrize("chips", [1, 4])
 def test_past_the_cap_both_hand_to_the_host(chips):
     """More backward edges than MAX_K_CAP: no retry, converged False and
-    no witnesses, on both paths."""
+    no witnesses, as the host reference reads it."""
     gr = _graph(5, cs.MAX_K_CAP + 200, False, back_fam=2, n_nodes=1024,
                 fam_lens=(64, 64, cs.MAX_K_CAP + 256, 32, 32))
     mesh = _mesh(chips)
     fam = cs.enumerate_backward(_family_graph(gr), mesh=mesh)
     for inc, cinc in ((1, 1, 1, 0, 0), (0, 0)), ((1, 1, 0, 0, 0), (1, 1)):
         got = cs.detect_cycles(fam.project(inc, cinc), mesh=mesh)
-        _same(got, cs.detect_cycles(_plain_graph(gr, inc, cinc), mesh=mesh))
+        _same(got, _host_sweep(gr["rank"], *_projection_edges(gr, inc,
+                                                              cinc)))
     assert got.converged is True and got.n_backward == 0
     got = cs.detect_cycles(fam.project((0, 0, 1, 0, 0), (0, 0)), mesh=mesh)
     assert got.n_backward == cs.MAX_K_CAP + 200 and not got.converged
@@ -251,29 +296,25 @@ def test_projection_scan_outputs_unchanged(seed, n_back, max_k):
     )
 
     gr = _graph(seed, n_back, True)
-    (pn, ps, pm), (bn, bs, bm) = gr["chains"]
-    masks = [jnp.asarray(m) for m in np.split(gr["mask"], gr["starts"][1:-1])]
+    g = _family_graph(gr)
 
     @jax.jit
-    def scan(rank, src, dst, masks, cn, cst, pm, bm):
+    def scan(rank, src, dst, mask, cn, cst, cms):
         return cs.projection_scan(
-            N_NODES, max_k, 64, rank, src, dst, masks,
-            proj_include_stack(FUSED), cn, cst, [pm, bm],
-            chain_include_stack(FUSED))
+            cs.FamilyGraph(N_NODES, rank, src, dst, mask, g.fam_lens, cn,
+                           cst, cms),
+            max_k, 64, proj_include_stack(FUSED), chain_include_stack(FUSED))
 
-    conv, over, bits = scan(
-        jnp.asarray(gr["rank"]), jnp.asarray(gr["src"]),
-        jnp.asarray(gr["dst"]), masks,
-        jnp.asarray(np.concatenate([pn, bn]).astype(np.int32)),
-        jnp.asarray(np.concatenate([ps, bs])), jnp.asarray(pm),
-        jnp.asarray(bm))
+    conv, over, bits = scan(g.rank, g.nc_src, g.nc_dst, g.base_mask,
+                            g.chain_nodes, g.chain_starts, g.chain_masks)
     assert (bool(conv), int(over), np.asarray(bits).tolist()) == \
         SCAN_GOLDEN[(seed, n_back, max_k)]
 
 
 def _top_level_edge_ops(jaxpr, n_edges):
     """Scatters and gathers over `n_edges` indices in `jaxpr`, outside any
-    cond branch (inner jits are looked into)."""
+    cond branch (inner jits are looked into), each with the shape of the
+    array it reads or writes."""
     found = []
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
@@ -282,7 +323,7 @@ def _top_level_edge_ops(jaxpr, n_edges):
         if name.startswith("scatter") or name == "gather":
             idx = eqn.invars[1].aval
             if idx.ndim and idx.shape[0] == n_edges:
-                found.append(name)
+                found.append((name, tuple(eqn.invars[0].aval.shape)))
         for p in eqn.params.values():
             inner = getattr(p, "jaxpr", None)
             if inner is not None and hasattr(inner, "eqns"):
@@ -302,15 +343,27 @@ def test_no_edge_scatter_or_rank_gather_before_the_cond():
         jnp.asarray(g.inc, jnp.int32), fam.chain_nodes, fam.chain_starts,
         fam.chain_masks, jnp.asarray(g.cinc, jnp.int32))
     assert _top_level_edge_ops(new.jaxpr, E) == []
-    # the plain program does both before its cond: two rank gathers,
-    # two endpoint scatter-maxes
-    p = _plain_graph(gr, g.inc, g.cinc)
-    old = jax.make_jaxpr(lambda *a: cs._sweep_kw(
-        *a, n_nodes=N_NODES, max_k=128, max_rounds=64))(
-        p.rank, p.nc_src, p.nc_dst, p.nc_mask, p.chain_nodes,
-        p.chain_starts, p.chain_mask)
-    assert sorted(_top_level_edge_ops(old.jaxpr, E)) == \
-        ["gather", "gather", "scatter-max", "scatter-max"]
+
+    # the rw fused program's version sweep: before its cond, only its
+    # enumeration's rank test (two gathers of the version ranks) and no
+    # scatter of its edges into a (max_k,)-sized endpoint table
+    from jepsen_tpu.checkers.elle import device_rw
+    from jepsen_tpu.checkers.elle.device_infer import pad_packed
+    from jepsen_tpu.history.soa import pack_txns
+    from jepsen_tpu.workloads import synth
+
+    p = pack_txns(synth.rw_history(n_txns=200, n_keys=6, seed=3),
+                  "rw-register")
+    h = pad_packed(p)
+    max_k = 40
+    rw = jax.make_jaxpr(lambda h: device_rw.rw_core_check(
+        h, p.n_keys, max_k=max_k))(h)
+    ops = _top_level_edge_ops(rw.jaxpr, h.mop_txn.shape[0])
+    n_versions = h.rd_elems.shape[0] + p.n_keys
+    assert [op for op, shape in ops if shape == (n_versions,)] == \
+        ["gather", "gather"]
+    assert not [op for op, shape in ops if op.startswith("scatter")
+                and shape in ((max_k,), (max_k + 1,))]
 
 
 @pytest.mark.parametrize("cycle", [False, True])

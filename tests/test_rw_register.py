@@ -449,16 +449,21 @@ def _chain_with_cycle(n_edges):
 
 def test_report_sweep_shares_one_program_across_edge_counts(monkeypatch):
     """Two graphs whose edge counts differ within one power of two give
-    the host's verdict and run one sweep program between them."""
+    the host's verdict and run one enumeration program and one sweep
+    program between them."""
+    import jax
+
     from jepsen_tpu import compilecache
     from jepsen_tpu.checkers.elle import txn_cycles
 
-    shapes = []
+    shapes = {}
     real = compilecache.call
 
     def spy(name, fn, *args, **kw):
-        if name == "cycle-sweep":
-            shapes.append(tuple(a.shape for a in args))
+        if name in ("cycle-sweep.enumerate", "cycle-sweep.families"):
+            shapes.setdefault(name, []).append(
+                (tuple(np.shape(a) for a in jax.tree_util.tree_leaves(args)),
+                 kw["fam_lens"]))
         return real(name, fn, *args, **kw)
 
     monkeypatch.setattr(compilecache, "call", spy)
@@ -469,5 +474,8 @@ def test_report_sweep_shares_one_program_across_edge_counts(monkeypatch):
         host = txn_cycles._cycle_regions(proj, 64, rank, use_device=False)
         assert dev is not None and host is not None
         assert set(np.concatenate(dev)) <= set(np.concatenate(host))
-    assert len(shapes) == 2 and len(set(shapes)) == 1
-    assert shapes[0][1] == (128,)
+    assert sorted(shapes) == ["cycle-sweep.enumerate",
+                              "cycle-sweep.families"]
+    for runs in shapes.values():
+        assert len(runs) == 2 and len(set(runs)) == 1
+        assert runs[0][0][1] == (128,) and runs[0][1] == (128,)
