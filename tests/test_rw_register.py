@@ -218,8 +218,9 @@ def test_device_rw_differential_valid(seed):
     _assert_device_matches_host(h)
 
 
-def test_device_rw_differential_anomalies():
-    cases = [
+def anomaly_histories():
+    """Small histories, one per anomaly the fused check reports."""
+    return [
         # wr cycle (G1c)
         concurrent_history(
             ([["w", "x", 1], ["r", "y", None]],
@@ -258,7 +259,10 @@ def test_device_rw_differential_anomalies():
             ([["w", "x", 1]], [["w", "x", 1]]),
         ),
     ]
-    for i, h in enumerate(cases):
+
+
+def test_device_rw_differential_anomalies():
+    for i, h in enumerate(anomaly_histories()):
         try:
             _assert_device_matches_host(h)
         except AssertionError as e:
